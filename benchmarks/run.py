@@ -408,6 +408,33 @@ def bench_serving_scale(fast: bool):
         stale_tokens=m.series(GAUGES.STALE_TOKENS).total)
 
 
+def _example_report(script: str, tag: str, fast: bool) -> dict:
+    """Run ``examples/<script>`` in a child process and parse its
+    ``<tag> {json}`` line.
+
+    The examples force simulated host devices, an XLA flag that must be
+    set before jax initializes, so they cannot run in this process.  The
+    child is pinned to the CPU: this process already holds the chip, and
+    a second process that reached for it would fail or hang."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.path.join(root, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    cmd = [sys.executable, os.path.join(root, "examples", script)]
+    if fast:
+        cmd.append("--fast")
+    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                         timeout=900)
+    if out.returncode != 0:
+        raise RuntimeError(f"{script} failed:\n{out.stdout}\n{out.stderr}")
+    return next(json.loads(l.split(" ", 1)[1]) for l in out.stdout.splitlines()
+                if l.startswith(f"{tag} "))
+
+
 def bench_elastic_churn(fast: bool):
     """Elastic recovery cost across an injected kill/rejoin schedule.
 
@@ -419,26 +446,7 @@ def bench_elastic_churn(fast: bool):
     steps lost to the failure, and wall-seconds from node death to the
     first step completed on the reshaped mesh.
     """
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, os.path.join(root, "examples",
-                                        "elastic_failover.py")]
-    if fast:
-        cmd.append("--fast")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"elastic churn bench failed:\n{out.stdout}"
-                           f"\n{out.stderr}")
-    rep = next(json.loads(l.split(" ", 1)[1]) for l in out.stdout.splitlines()
-               if l.startswith("CHURN_REPORT "))
+    rep = _example_report("elastic_failover.py", "CHURN_REPORT", fast)
     steps = rep["steps"]
     row("elastic_churn_train", rep["total_wall_s"] / steps * 1e6,
         f"tok_s={rep['tokens_per_s']:.1f};recoveries={rep['recoveries']}",
@@ -590,26 +598,7 @@ def bench_vcluster_fairness(fast: bool):
     checkpoint-then-evict preemption cost (steps lost on resume), and
     the monitor stream's end-to-end event lag.
     """
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, os.path.join(root, "examples",
-                                        "multitenant_fabric.py")]
-    if fast:
-        cmd.append("--fast")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"vcluster fairness bench failed:\n{out.stdout}"
-                           f"\n{out.stderr}")
-    rep = next(json.loads(l.split(" ", 1)[1]) for l in out.stdout.splitlines()
-               if l.startswith("VCLUSTER_REPORT "))
+    rep = _example_report("multitenant_fabric.py", "VCLUSTER_REPORT", fast)
     fair, fifo, prem = rep["fair"], rep["fifo"], rep["preemption"]
     mk = max(fair["alice"]["makespan_s"], fair["bob"]["makespan_s"])
     row("vcluster_fair_share", mk * 1e6,
@@ -638,26 +627,7 @@ def bench_scenarios(fast: bool):
     goodput ratio, p99 TTFT/latency, steps lost to preemption and the
     $-chargeback total.
     """
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, os.path.join(root, "examples",
-                                        "scenario_chaos.py")]
-    if fast:
-        cmd.append("--fast")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"scenario chaos bench failed:\n{out.stdout}"
-                           f"\n{out.stderr}")
-    rep = next(json.loads(l.split(" ", 1)[1]) for l in out.stdout.splitlines()
-               if l.startswith("SCENARIO_REPORT "))
+    rep = _example_report("scenario_chaos.py", "SCENARIO_REPORT", fast)
     chaos_applied = sum(1 for c in rep["chaos"] if c.get("applied"))
     row("scenario_chaos_run", rep["wall_s"] * 1e6,
         f"skew={rep['fairshare_skew']};chaos={chaos_applied}",
@@ -688,25 +658,7 @@ def bench_rl(fast: bool):
     lag, stale drops), one the chaos/recovery accounting (steps lost
     vs the checkpoint bound, tickets requeued by the killed actor).
     """
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    cmd = [sys.executable, os.path.join(root, "examples", "rl_cotenants.py")]
-    if fast:
-        cmd.append("--fast")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"rl cotenants bench failed:\n{out.stdout}"
-                           f"\n{out.stderr}")
-    rep = next(json.loads(l.split(" ", 1)[1]) for l in out.stdout.splitlines()
-               if l.startswith("RL_REPORT "))
+    rep = _example_report("rl_cotenants.py", "RL_REPORT", fast)
     row("rl_rollout_fleet", rep["wall_s"] * 1e6 / max(rep["trained"], 1),
         f"tok_s={rep['rollout_tok_s']};rollouts={rep['rollouts_pushed']}",
         rollout_tok_s=rep["rollout_tok_s"], trained=rep["trained"],
@@ -753,6 +705,8 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="run only benches whose name contains this substring")
     args, _ = ap.parse_known_args()
+    from repro.launch.cli import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in BENCHES:
         if args.only and args.only not in name:

@@ -399,11 +399,13 @@ class ClusterBackend:
         if job.max_replicas > 1:
             return run_serve_replicated(handle, job, metrics)
         engine = build_engine(job, registry_out=metrics)
-        queue = WorkQueue(serve_requests(job),
-                          lease_timeout=job.lease_timeout)
         if job.warmup:
             with engine.mesh:
                 engine.warmup()
+        # requests enqueue after warmup: TTFT and latency count from
+        # enqueue, and compiling is set-up, not queueing
+        queue = WorkQueue(serve_requests(job),
+                          lease_timeout=job.lease_timeout)
         handle.probe("completed",
                      lambda: int(metrics.series(GAUGES.COMPLETED).total))
         handle._transition(WorkloadState.RUNNING, slots=job.slots)

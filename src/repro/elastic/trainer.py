@@ -81,7 +81,8 @@ class ElasticTrainSpec:
     seed: int = 0
     data_seed: int = 17
     fail_at: int = -1                      # inject ONE crash at this step
-    backoff_limit: int = 2                 # non-churn failures tolerated
+    # non-churn failures tolerated; XLA errors (compile, OOM) never retry
+    backoff_limit: int = 2
     # A drained pod's node is "dead": by default it does NOT write a final
     # checkpoint (recovery cost = steps since the last periodic save, the
     # honest number).  Graceful scale-up preemptions always save.
@@ -279,6 +280,7 @@ class ElasticTrainer:
         self._seg_last = -1               # current segment's last step
         self._losses: Dict[int, float] = {}     # step -> loss (host)
         self._injected = False
+        self._fatal: Optional[BaseException] = None
         self._final: Dict[str, Any] = {}
 
     # ------------------------------------------------------------- segments
@@ -454,7 +456,13 @@ class ElasticTrainer:
         plan, bplan = decision.plan, decision.batch
 
         def segment_fn(ctx):
-            return self._train_segment(ctx, plan, bplan, graceful)
+            try:
+                return self._train_segment(ctx, plan, bplan, graceful)
+            except jax.errors.JaxRuntimeError as e:
+                # a compile failure or device OOM repeats identically on
+                # every retry: surface it on the first attempt
+                self._fatal = e
+                raise
 
         # a node can die between the capacity decision and this submit; the
         # stale plan then over-asks and the caller replans on the survivors
@@ -600,6 +608,10 @@ class ElasticTrainer:
                     if spec.verbose:
                         print(f"[elastic] segment {seg_idx}: {pod.error!s}"
                               .splitlines()[0] + " -> rescale + restore")
+                elif self._fatal is not None:
+                    raise RuntimeError(
+                        f"elastic training failed: {self._fatal}") \
+                        from self._fatal
                 else:
                     failures += 1
                     if failures > spec.backoff_limit:

@@ -1,11 +1,12 @@
 """Shared kernel-runtime knobs.
 
-On this CPU container every Pallas wrapper defaults to interpret=True
-(the kernel body runs in Python, validating BlockSpec/grid logic); on a
-TPU runtime set ``REPRO_PALLAS_COMPILE=1`` (or pass interpret=False) to
-compile.  Train-hot-loop kernels (fused xent / fused AdamW) are
-additionally gated by their own env switches because interpret mode is
-far too slow to sit inside every CPU test's train step.
+The backend decides how a Pallas kernel runs: compiled by Mosaic on a
+TPU, interpreted (the kernel body evaluated as ordinary JAX ops, which
+validates the BlockSpec/grid logic) everywhere else.  The train-hot-loop
+kernels (fused xent / fused AdamW) are also gated on the TPU backend,
+because interpret mode is far too slow to sit inside every CPU test's
+train step; ``REPRO_FUSED_XENT=1`` / ``REPRO_FUSED_ADAMW=1`` force them
+on elsewhere for debugging, and ``=0`` takes them off a TPU.
 """
 from __future__ import annotations
 
@@ -17,8 +18,6 @@ NEG_INF = -1e30
 
 
 def interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE"):
-        return False
     return jax.default_backend() != "tpu"
 
 

@@ -1,8 +1,7 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On this CPU container the wrappers run interpret=True (the kernel body
-executes in Python, validating the BlockSpec/grid logic); on a TPU runtime
-set ``REPRO_PALLAS_COMPILE=1`` (or pass interpret=False) to compile them.
+The backend decides how they run: compiled on a TPU, interpreted (the
+kernel body evaluated as ordinary JAX ops) everywhere else.
 """
 from __future__ import annotations
 

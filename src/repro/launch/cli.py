@@ -10,11 +10,31 @@ flag surface entirely: the file IS the workload declaration
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 from typing import Optional
+
+import jax
 
 from repro.api.resources import WorkloadSpec, load_manifest
 
 DEFAULT_ARCH = "phi4-mini-3.8b"
+# the persistent compile cache's fixed home inside the checkout (listed in
+# .gitignore): the path is part of the cache key, so it must never move
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX; otherwise the
+    cache lives at ``COMPILE_CACHE_DIR``, so a cold full-width compile is
+    paid once per checkout rather than once per process."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def add_arch(ap: argparse.ArgumentParser, *, default: str = DEFAULT_ARCH,

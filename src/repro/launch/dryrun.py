@@ -120,7 +120,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = _mem_dict(compiled)
-    cost = hlo_mod.xla_cost(compiled)
+    cost = compiled.cost_analysis()
     text = compiled.as_text()
     coll = hlo_mod.collective_bytes(text)
     counts = hlo_mod.collective_counts(text)
@@ -164,6 +164,7 @@ def main() -> None:
                     help="sweep every assigned (arch x shape) cell")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    cli.enable_compile_cache()
 
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)

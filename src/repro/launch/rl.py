@@ -73,6 +73,7 @@ def main():
                          "the crash loop restores from the latest "
                          "checkpoint and finishes the run")
     args = ap.parse_args()
+    cli.enable_compile_cache()
     spec = cli.manifest_spec(args, RLJob.KIND)
     if spec is None:
         spec = rl_job(args.arch, learner_steps=args.learner_steps,

@@ -212,6 +212,7 @@ def main():
                          "cycled) — the workload continuous batching "
                          "wins on")
     args = ap.parse_args()
+    cli.enable_compile_cache()
     gen_lens = None
     if args.spread:
         gen_lens = [max(1, args.gen // (2 ** i)) for i in range(4)]

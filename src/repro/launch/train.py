@@ -95,6 +95,7 @@ def main():
                          "(lax.scan hot loop); ckpt/log cadences snap up "
                          "to multiples of this")
     args = ap.parse_args()
+    cli.enable_compile_cache()
     spec = cli.manifest_spec(args, TrainJob.KIND)
     if spec is None:
         spec = train_job(args.arch, steps=args.steps, seq=args.seq,

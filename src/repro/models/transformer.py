@@ -51,12 +51,18 @@ def _attn_mlp_schema(cfg: ModelConfig, G: int) -> Dict[str, PSpec]:
     heads_div = H % 16 == 0
     hq = "tp_heads" if heads_div else None
     hd = "head_dim" if heads_div else "tp_head_dim"
+    # the projections contract over D (q/k/v) and H*dh (out), not over
+    # their second-to-last dim: state the init scale, or the scores of a
+    # fresh model saturate (std 1/sqrt(H) instead of 1/sqrt(D))
+    sd, so = D ** -0.5, (H * dh) ** -0.5
     s: Dict[str, PSpec] = {
         "ln1": PSpec((G, D), ("layers", None), "zeros"),
-        "wq": PSpec((G, D, H, dh), ("layers", "fsdp", hq, hd)),
-        "wk": PSpec((G, D, KV, dh), ("layers", "fsdp", "tp_kv_heads", hd)),
-        "wv": PSpec((G, D, KV, dh), ("layers", "fsdp", "tp_kv_heads", hd)),
-        "wo": PSpec((G, H, dh, D), ("layers", hq, hd, "fsdp")),
+        "wq": PSpec((G, D, H, dh), ("layers", "fsdp", hq, hd), scale=sd),
+        "wk": PSpec((G, D, KV, dh), ("layers", "fsdp", "tp_kv_heads", hd),
+                    scale=sd),
+        "wv": PSpec((G, D, KV, dh), ("layers", "fsdp", "tp_kv_heads", hd),
+                    scale=sd),
+        "wo": PSpec((G, H, dh, D), ("layers", hq, hd, "fsdp"), scale=so),
         "ln2": PSpec((G, D), ("layers", None), "zeros"),
         "wg": PSpec((G, D, F), ("layers", "fsdp", "tp_ff")),
         "wu": PSpec((G, D, F), ("layers", "fsdp", "tp_ff")),

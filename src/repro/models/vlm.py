@@ -28,12 +28,17 @@ def cross_schema(cfg: ModelConfig, G: int) -> Dict[str, PSpec]:
     hd_ax = "head_dim" if heads_div else "tp_head_dim"
     return {
         "ln1": PSpec((G, D), ("layers", None), "zeros"),
-        "wq": PSpec((G, D, H, dh), ("layers", "fsdp", hq, hd_ax)),
-        "wk": PSpec((G, Vd, KV, dh), ("layers", None, "tp_kv_heads", hd_ax)),
-        "wv": PSpec((G, Vd, KV, dh), ("layers", None, "tp_kv_heads", hd_ax)),
+        # fan-in is the contracted dim (see transformer._attn_mlp_schema)
+        "wq": PSpec((G, D, H, dh), ("layers", "fsdp", hq, hd_ax),
+                    scale=D ** -0.5),
+        "wk": PSpec((G, Vd, KV, dh), ("layers", None, "tp_kv_heads", hd_ax),
+                    scale=Vd ** -0.5),
+        "wv": PSpec((G, Vd, KV, dh), ("layers", None, "tp_kv_heads", hd_ax),
+                    scale=Vd ** -0.5),
         "k_norm": PSpec((G, dh), ("layers", None), "zeros"),
         "q_norm": PSpec((G, dh), ("layers", None), "zeros"),
-        "wo": PSpec((G, H, dh, D), ("layers", hq, hd_ax, "fsdp")),
+        "wo": PSpec((G, H, dh, D), ("layers", hq, hd_ax, "fsdp"),
+                    scale=(H * dh) ** -0.5),
         "gate_attn": PSpec((G,), ("layers",), "zeros"),
         "ln2": PSpec((G, D), ("layers", None), "zeros"),
         "wg": PSpec((G, D, F), ("layers", "fsdp", "tp_ff")),

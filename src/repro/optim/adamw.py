@@ -9,10 +9,12 @@ inherited from the param logical axes.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import OptimizerConfig
 from repro.kernels.common import fused_adamw_default, interpret_default
@@ -129,8 +131,22 @@ def clip_by_global_norm(grads, max_norm: float):
     return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), norm
 
 
+def _fused_leaf(p, g, m, v, lr, bc1, bc2, shd, **hp):
+    """The fused kernel on one leaf.  A Mosaic kernel cannot be
+    partitioned by XLA, so on a multi-device mesh it runs per shard under
+    ``shard_map`` (the update is elementwise: shards never talk)."""
+    from repro.kernels.adamw_update import adamw_update
+    fn = functools.partial(adamw_update, interpret=interpret_default(), **hp)
+    if shd is None or shd.mesh.size == 1:
+        return fn(p, g, m, v, lr, bc1, bc2)
+    spec, rep = shd.spec, P()
+    return jax.shard_map(fn, mesh=shd.mesh, in_specs=(spec,) * 4 + (rep,) * 3,
+                         out_specs=(spec,) * 3, check_vma=False)(
+                             p, g, m, v, lr, bc1, bc2)
+
+
 def apply_updates(param_schema, params, grads, state, ocfg: OptimizerConfig,
-                  *, fused: Optional[bool] = None):
+                  *, fused: Optional[bool] = None, shardings=None):
     """One AdamW step.  Returns (new_params, new_state, stats).
 
     Memory: the elementwise update math runs in f32, so applying it to a
@@ -144,7 +160,8 @@ def apply_updates(param_schema, params, grads, state, ocfg: OptimizerConfig,
     kernel per leaf, no f32 temp trees AND no layered scan needed.
     None = backend default (TPU on, CPU off; ``REPRO_FUSED_ADAMW=1``
     forces it on CPU under interpret mode).  Quantized / factored state
-    always keeps the unfused path.
+    always keeps the unfused path.  ``shardings`` (the params' NamedSharding
+    tree) lets the fused kernel run per shard on a multi-device mesh.
     """
     if fused is None:
         fused = fused_adamw_default()
@@ -158,16 +175,14 @@ def apply_updates(param_schema, params, grads, state, ocfg: OptimizerConfig,
     bc1 = 1.0 - ocfg.b1 ** t
     bc2 = 1.0 - ocfg.b2 ** t
 
-    def leaf(sch, p, g, m, v):
+    def leaf(sch, p, g, m, v, shd):
         # the fused kernel streams tiles through VMEM, so even stacked
         # "layers" leaves go through whole (no scan, no temp blowup)
         if (fused and not isinstance(m, dict) and not isinstance(v, dict)
                 and m.dtype == jnp.float32 and v.dtype == jnp.float32):
-            from repro.kernels.adamw_update import adamw_update
             wd = ocfg.weight_decay if len(sch.shape) >= 2 else 0.0
-            return adamw_update(p, g, m, v, lr, bc1, bc2, b1=ocfg.b1,
-                                b2=ocfg.b2, eps=ocfg.eps, weight_decay=wd,
-                                interpret=interpret_default())
+            return _fused_leaf(p, g, m, v, lr, bc1, bc2, shd, b1=ocfg.b1,
+                               b2=ocfg.b2, eps=ocfg.eps, weight_decay=wd)
         layered = (sch.axes and sch.axes[0] == "layers"
                    and len(sch.shape) >= 2 and sch.shape[0] > 1)
         if not layered:
@@ -181,16 +196,17 @@ def apply_updates(param_schema, params, grads, state, ocfg: OptimizerConfig,
         _, (np_, nm, nv) = jax.lax.scan(step, None, (p, g, m, v))
         return np_, nm, nv
 
-    def rec(sch, p, g, m, v):
+    def rec(sch, p, g, m, v, shd):
         if is_pspec(sch):
-            return leaf(sch, p, g, m, v)
-        out = {k: rec(sch[k], p[k], g[k], m[k], v[k]) for k in sch}
+            return leaf(sch, p, g, m, v, shd)
+        out = {k: rec(sch[k], p[k], g[k], m[k], v[k],
+                      None if shd is None else shd[k]) for k in sch}
         new_p = {k: out[k][0] for k in out}
         new_m = {k: out[k][1] for k in out}
         new_v = {k: out[k][2] for k in out}
         return new_p, new_m, new_v
 
     new_params, new_m, new_v = rec(param_schema, params, grads,
-                                   state["m"], state["v"])
+                                   state["m"], state["v"], shardings)
     new_state = {"m": new_m, "v": new_v, "count": count}
     return new_params, new_state, {"grad_norm": gnorm, "lr": lr}

@@ -28,17 +28,6 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
-def xla_cost(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions: older releases
-    return a per-computation list of dicts, newer ones a flat dict."""
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
     "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -189,7 +189,7 @@ def _train_pieces(cfg: ModelConfig, par: ParallelConfig,
             grads = jax.tree.map(lambda g: g / accum, grads)
 
         new_params, new_opt, stats = adamw.apply_updates(
-            schema, params, grads, opt_state, ocfg)
+            schema, params, grads, opt_state, ocfg, shardings=param_shd)
         metrics = {"loss": loss.astype(jnp.float32), **stats}
         return new_params, new_opt, metrics
 

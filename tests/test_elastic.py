@@ -200,6 +200,29 @@ def test_trainer_unschedulable_is_bounded(tmp_path):
         trainer.run()
 
 
+def test_trainer_xla_error_fails_on_first_attempt(monkeypatch):
+    """A compile failure or device OOM (an XLA runtime error) repeats on
+    every retry: it surfaces at once, not after backoff_limit retries."""
+    from repro.elastic import ElasticTrainer, ElasticTrainSpec
+
+    attempts = []
+
+    def oom(self, ctx, plan, bplan, graceful):
+        attempts.append(plan)
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: hbm")
+
+    monkeypatch.setattr(ElasticTrainer, "_train_segment", oom)
+    cfg = registry.get_smoke("phi4-mini-3.8b")
+    par = registry.get_parallel("phi4-mini-3.8b")
+    spec = ElasticTrainSpec(cfg, par, OptimizerConfig(), steps=4, seq_len=32,
+                            global_batch=4, base_shape=(1, 1), max_data=1,
+                            backoff_limit=2, verbose=False)
+    trainer = ElasticTrainer(Cluster(devices=jax.devices()), spec)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        trainer.run()
+    assert len(attempts) == 1
+
+
 # ---------------------------------------------------- end-to-end churn run
 
 @pytest.mark.slow

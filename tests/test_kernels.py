@@ -140,6 +140,33 @@ def test_softmax_xent_grad(softcap):
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("R,V,br,bv", [
+    (200, 1000, 64, 384),      # 4 row blocks (last 8 rows), 3 vocab (232)
+    (72, 640, 64, 128),        # vocab a whole number of tiles, rows not
+])
+def test_softmax_xent_bf16_ragged_tiles(R, V, br, bv):
+    """bf16 logits over partial row AND vocab blocks: the cdiv grid's
+    out-of-range tails are masked (forward) and dropped (backward), with
+    no padded copy of the logits."""
+    from repro.kernels.xent import softmax_xent
+    k1, k2 = jax.random.split(jax.random.key(9))
+    logits = (4.0 * jax.random.normal(k1, (R, V))).astype(jnp.bfloat16)
+    labels = jax.random.randint(k2, (R,), 0, V)
+    out = softmax_xent(logits, labels, block_r=br, block_v=bv,
+                       interpret=True)
+    want = ref.softmax_xent_ref(logits, labels)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda x: jnp.mean(softmax_xent(
+        x, labels, block_r=br, block_v=bv, interpret=True)))(logits)
+    g_ref = jax.grad(lambda x: jnp.mean(ref.softmax_xent_ref(x, labels)))(
+        logits)
+    assert g.shape == (R, V) and g.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(g, np.float32),
+                               np.asarray(g_ref, np.float32),
+                               rtol=2e-2, atol=1e-6)
+
+
 def test_softmax_xent_extreme_logits():
     """Online logsumexp must not overflow where naive exp would."""
     from repro.kernels.xent import softmax_xent
@@ -159,8 +186,11 @@ def test_softmax_xent_extreme_logits():
 
 @pytest.mark.parametrize("shape", [
     (256, 128),                # exact tiles
-    (3, 100, 37),              # ragged flatten -> padding tail
-    (5,),                      # tiny 1-D leaf, all padding
+    (3, 100, 37),              # 300 rows over 64-row tiles, last dim whole
+    (5,),                      # tiny 1-D leaf: one tile, mostly out of range
+    (3, 100, 300),             # rows and last dim both cut, both ragged
+    (1000,),                   # 1-D leaf: one row, ragged lane tail
+    (7, 5, 3, 260),            # leading dims collapse to 105 rows
 ])
 @pytest.mark.parametrize("pdtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("weight_decay", [0.0, 0.1])
@@ -173,8 +203,11 @@ def test_adamw_update(shape, pdtype, weight_decay):
     v = jnp.abs(jax.random.normal(ks[3], shape)).astype(jnp.float32)
     lr, bc1, bc2 = jnp.float32(3e-4), jnp.float32(0.271), jnp.float32(0.0297)
     hp = dict(b1=0.9, b2=0.95, eps=1e-8, weight_decay=weight_decay)
+    # (64, 128) tiles: the leaf's (leading dims, last dim) view is cut
+    # into partial edge tiles, whose out-of-range part is never written
     new_p, new_m, new_v = adamw_update(p, g, m, v, lr, bc1, bc2,
-                                       block_rows=64, interpret=True, **hp)
+                                       block_rows=64, block_cols=128,
+                                       interpret=True, **hp)
     want_p, want_m, want_v = ref.adamw_update_ref(p, g, m, v, lr, bc1, bc2,
                                                   **hp)
     assert new_p.shape == shape and new_p.dtype == pdtype

@@ -30,7 +30,7 @@ def test_analytic_flops_vs_xla_small_dense():
     bundle = steps_mod.build_train(cfg, par, ocfg, mesh, shape)
     with mesh:
         compiled = bundle.lower().compile()
-    xla = hlo_mod.xla_cost(compiled).get("flops", 0.0)
+    xla = compiled.cost_analysis().get("flops", 0.0)
     # fwd * (1 fwd + 2 bwd) -- no remat here
     ours = flops_mod.forward_flops(cfg, shape, 1) * 3.0
     assert xla > 0
