@@ -5,14 +5,27 @@ every aspect of a machine learning workflow" (CHASE-CI §VI, Figs 3-6,
 Table I).  This registry provides counters / gauges / histograms plus
 timestamped series, and renders the paper's Table I (per-step resource
 summary) from StepReports.
+
+Spans (``Registry.span``) time regions of the program on
+``time.monotonic`` and mirror each into the profiler's trace as a
+``jax.profiler.TraceAnnotation``, so a trace holds them on the same clock
+as the device's operations.  ``SlowSteps`` flags a step far slower than
+its recent median and notes on the step's span what the process was
+doing meanwhile.
 """
 from __future__ import annotations
 
+import collections
+import gc
+import resource
+import statistics
 import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -80,10 +93,62 @@ class Series:
                 "p50": pct(50), "p99": pct(99)}
 
 
+class Span:
+    """One timed region: ``name``, ``start`` and ``end`` on the registry's
+    clock, ``parent`` (the innermost span open on the same thread when it
+    began, or None) and ``attrs``.  Made by ``Registry.span``; while
+    open it is also a ``TraceAnnotation`` of the same name."""
+    __slots__ = ("name", "start", "end", "parent", "attrs", "_registry",
+                 "_annotation")
+
+    def __init__(self, registry: "Registry", name: str,
+                 attrs: Dict[str, Any]):
+        self.name = name
+        self.attrs = attrs
+        self.start = self.end = None
+        self.parent: Optional[Span] = None
+        self._registry = registry
+        self._annotation = None
+
+    @property
+    def duration(self) -> float:
+        return self.end - self.start
+
+    def __enter__(self) -> "Span":
+        reg = self._registry
+        stack = reg._open_spans()
+        self.parent = stack[-1] if stack else None
+        stack.append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self.start = reg.clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        reg = self._registry
+        self.end = reg.clock()
+        self._annotation.__exit__(*exc)
+        reg._open_spans().pop()
+        self._annotation = self._registry = None
+        with reg._span_lock:
+            reg._spans.append(self)
+
+
+MAX_SPANS = 1 << 16        # finished spans a registry keeps, newest last
+
+
 class Registry:
-    def __init__(self):
+    def __init__(self, clock: Callable[[], float] = time.monotonic):
         self._lock = threading.Lock()
         self._series: Dict[str, Series] = {}
+        # spans: the clock they are stamped on, the finished ones in a
+        # ring (a long-lived server keeps the newest), and each thread's
+        # stack of open ones (a new span's parent is the top)
+        self.clock = clock
+        self._spans: "collections.deque[Span]" = collections.deque(
+            maxlen=MAX_SPANS)
+        self._span_lock = threading.Lock()
+        self._local = threading.local()
         # listeners get (name, value, ts) on every inc/gauge/timer — the
         # near-real-time monitor (repro.vcluster.monitor) taps this to
         # stream throughput gauges without polling
@@ -115,15 +180,40 @@ class Registry:
         self.series(name).record(value)
         self._notify(name, value)
 
+    def span(self, name: str, **attrs) -> Span:
+        """A context manager timing a region as a ``Span`` (which it
+        yields, so the region can add ``attrs``); kept when it closes,
+        exception or not.  Costs two clock reads and an append, and a
+        profiler annotation that is a no-op unless a trace is
+        recording."""
+        return Span(self, name, attrs)
+
+    def _open_spans(self) -> List[Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def spans(self, name: Optional[str] = None, lo: float = float("-inf"),
+              hi: float = float("inf")) -> List[Span]:
+        """Finished spans (of ``name``, if given) that ended inside
+        [lo, hi], oldest first; the ring keeps the newest ``MAX_SPANS``."""
+        with self._span_lock:
+            spans = list(self._spans)
+        return [s for s in spans if (name is None or s.name == name)
+                and lo <= s.end <= hi]
+
     @contextmanager
     def timer(self, name: str):
-        t0 = time.perf_counter()
+        """A span whose duration is also recorded in the series
+        ``name``."""
+        span = self.span(name)
         try:
-            yield
+            with span:
+                yield
         finally:
-            dt = time.perf_counter() - t0
-            self.series(name).record(dt)
-            self._notify(name, dt)
+            self.series(name).record(span.duration)
+            self._notify(name, span.duration)
 
     def scrape(self) -> Dict[str, float]:
         with self._lock:
@@ -147,12 +237,104 @@ class Registry:
         return "\n".join(lines)
 
 
-def record_serving_totals(registry: "Registry", useful_tokens: int,
-                          wall_s: float, decode_s: float) -> None:
-    """Deprecated alias — the implementation (and the single source of
-    the ``serve/*`` gauge names) moved to ``repro.serving.report``."""
-    from repro.serving.report import record_serving_totals as impl
-    impl(registry, useful_tokens, wall_s, decode_s)
+# a step slower than SLOW_FACTOR times the median of the last SLOW_WINDOW
+# steps is slow; no step is judged before SLOW_MIN_STEPS have been seen
+SLOW_FACTOR = 3.0
+SLOW_WINDOW = 64
+SLOW_MIN_STEPS = 8
+CPU_STAT = "/sys/fs/cgroup/cpu.stat"
+
+
+class _GcPauses:
+    """Seconds the interpreter has spent collecting garbage, summed by a
+    ``gc.callbacks`` hook (one per process, installed on first use)."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._t0: Optional[float] = None
+        self._installed = False
+
+    def install(self) -> None:
+        if not self._installed:
+            self._installed = True
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self._t0 = None
+
+
+_GC_PAUSES = _GcPauses()
+
+
+def _cpu_stat() -> Optional[Dict[str, int]]:
+    """The cgroup's CPU counters (``nr_throttled``, ``throttled_usec``,
+    ...), or None where the file is missing."""
+    try:
+        with open(CPU_STAT) as f:
+            return {k: int(v) for k, v in
+                    (line.split() for line in f if line.strip())}
+    except (OSError, ValueError):
+        return None
+
+
+class SlowSteps:
+    """Flags a step whose wall time exceeds ``SLOW_FACTOR`` times the
+    median of the last ``SLOW_WINDOW`` steps: it increments ``counter``
+    and records on the step's span what the process was doing during the
+    step.  Wrap each step as::
+
+        with registry.span("step") as span:
+            before = slow.begin()
+            ...
+        slow.end(span, before)
+
+    A slow step's span gains ``median_s``, the involuntary context
+    switches (``invol_switches``) and user plus system CPU seconds
+    (``cpu_s``) of the process over the step, the garbage collector's
+    pauses inside it (``gc_s``) and, where the cgroup's ``cpu.stat``
+    exists, the throttled periods and seconds since its previous read
+    (``nr_throttled``, ``throttled_s``).  That file is read here once and
+    then only on slow steps."""
+
+    def __init__(self, registry: Registry, counter: str):
+        self._registry = registry
+        self._counter = counter
+        self._walls: "collections.deque[float]" = collections.deque(
+            maxlen=SLOW_WINDOW)
+        self._cpu_stat = _cpu_stat()
+        _GC_PAUSES.install()
+
+    def begin(self) -> Tuple[Any, float]:
+        return resource.getrusage(resource.RUSAGE_SELF), _GC_PAUSES.seconds
+
+    def end(self, span: Span, before: Tuple[Any, float]) -> bool:
+        """Judge the closed ``span``; True if the step was slow."""
+        wall = span.duration
+        median = (statistics.median(self._walls)
+                  if len(self._walls) >= SLOW_MIN_STEPS else None)
+        self._walls.append(wall)
+        if median is None or wall <= SLOW_FACTOR * median:
+            return False
+        ru0, gc0 = before
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        span.attrs.update(
+            median_s=median, invol_switches=ru.ru_nivcsw - ru0.ru_nivcsw,
+            cpu_s=(ru.ru_utime + ru.ru_stime) - (ru0.ru_utime + ru0.ru_stime),
+            gc_s=_GC_PAUSES.seconds - gc0)
+        now = _cpu_stat()
+        if now is not None and self._cpu_stat is not None:
+            span.attrs.update(
+                nr_throttled=now.get("nr_throttled", 0)
+                - self._cpu_stat.get("nr_throttled", 0),
+                throttled_s=1e-6 * (now.get("throttled_usec", 0)
+                                    - self._cpu_stat.get("throttled_usec", 0)))
+        self._cpu_stat = now
+        self._registry.inc(self._counter)
+        return True
 
 
 @dataclass

@@ -35,8 +35,9 @@ def sharded_cross_entropy(ctx, x, labels, head, *, softcap=None):
     The gold logit is extracted with a one-hot einsum (elementwise + reduce
     partitions cleanly; a gather over a sharded vocab would not).
     """
-    logits = jnp.einsum("bsd,vd->bsv", x, head)            # bf16 compute
-    logits = ctx.cons(logits, ("batch", "act_seq_sharded", "act_vocab"))
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x, head)        # bf16 compute
+        logits = ctx.cons(logits, ("batch", "act_seq_sharded", "act_vocab"))
     lf = logits.astype(jnp.float32)
     if softcap is not None:
         lf = softcap * jnp.tanh(lf / softcap)
@@ -70,7 +71,7 @@ def weighted_cross_entropy(x, labels, head, weights, *, denom=None,
         fused = fused_xent_default()
 
     def fn(xc, lc, wc):
-        logits = jnp.einsum("bcd,vd->bcv", xc, head).astype(jnp.float32)
+        logits = _head_logits(xc, head)
         if fused:
             from repro.kernels.xent import softmax_xent
             V = head.shape[0]
@@ -104,6 +105,13 @@ def weighted_cross_entropy(x, labels, head, weights, *, denom=None,
     return total / denom
 
 
+def _head_logits(x_chunk, head):
+    """x (B,c,D) @ head (V,D) -> f32 logits (B,c,V), under the ``lm_head``
+    scope (the backward's ops inherit it)."""
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("bcd,vd->bcv", x_chunk, head).astype(jnp.float32)
+
+
 def _chunk_nll(x_chunk, labels_chunk, head):
     """x (B,c,D) @ head (V,D) -> mean-able NLL terms for one chunk (f32)."""
     logits = jnp.einsum("bcd,vd->bcv", x_chunk, head).astype(jnp.float32)
@@ -127,16 +135,13 @@ def chunked_cross_entropy(x, labels, head, *, softcap=None, chunk: int = 512,
         fused = fused_xent_default()
 
     def fn(xc, lc):
+        logits = _head_logits(xc, head)
         if fused:
             from repro.kernels.xent import softmax_xent
-            V = head.shape[0]
-            logits = jnp.einsum("bcd,vd->bcv", xc,
-                                head).astype(jnp.float32)
-            nll = softmax_xent(logits.reshape(-1, V), lc.reshape(-1),
-                               softcap=softcap,
+            nll = softmax_xent(logits.reshape(-1, head.shape[0]),
+                               lc.reshape(-1), softcap=softcap,
                                interpret=interpret_default())
             return jnp.sum(nll)
-        logits = jnp.einsum("bcd,vd->bcv", xc, head).astype(jnp.float32)
         if softcap is not None:
             logits = softcap * jnp.tanh(logits / softcap)
         m = jnp.max(logits, axis=-1)

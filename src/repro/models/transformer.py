@@ -94,15 +94,18 @@ def insert_kv(cache, k, v, pos):
     entry >= cache length writes nothing — a free/overflowed slot is a
     no-op rather than an out-of-bounds clamp.
     """
-    pos = jnp.asarray(pos)
-    if pos.ndim == 0:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos, axis=1)
-        return k_cache, v_cache
-    S = cache["k"].shape[1]
-    hit = (jnp.arange(S)[None, :] == pos[:, None])[..., None, None]  # (B,S,1,1)
-    return (jnp.where(hit, k, cache["k"]),
-            jnp.where(hit, v, cache["v"]))
+    with jax.named_scope("kv_write"):
+        pos = jnp.asarray(pos)
+        if pos.ndim == 0:
+            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos,
+                                                          axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos,
+                                                          axis=1)
+            return k_cache, v_cache
+        S = cache["k"].shape[1]
+        hit = (jnp.arange(S)[None, :] == pos[:, None])[..., None, None]
+        return (jnp.where(hit, k, cache["k"]),
+                jnp.where(hit, v, cache["v"]))
 
 
 def _tp_boundary(ctx: ModelCtx, h, mode: str, tag: str):
@@ -118,6 +121,13 @@ def _tp_boundary(ctx: ModelCtx, h, mode: str, tag: str):
 
 def attention_part(ctx: ModelCtx, p, x, *, window, mode, positions, cache, pos):
     """Pre-norm attention sub-block shared by dense/moe/hybrid kinds."""
+    with jax.named_scope("attn"):
+        return _attention_part(ctx, p, x, window=window, mode=mode,
+                               positions=positions, cache=cache, pos=pos)
+
+
+def _attention_part(ctx: ModelCtx, p, x, *, window, mode, positions, cache,
+                    pos):
     cfg = ctx.cfg
     strategy = attn_mod.attn_strategy(ctx)
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -149,12 +159,14 @@ def attention_part(ctx: ModelCtx, p, x, *, window, mode, positions, cache, pos):
 
 def mlp_part(ctx: ModelCtx, p, x, mode: str = "train"):
     cfg = ctx.cfg
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    h = _tp_boundary(ctx, h, mode, "mlp_in")
-    out = swiglu(ctx, {"wg": p["wg"], "wu": p["wu"], "wo": p["wo_mlp"]}, h)
-    if cfg.post_norm:
-        out = rms_norm(out, p["ln2_post"], cfg.norm_eps)
-    return x + out
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        h = _tp_boundary(ctx, h, mode, "mlp_in")
+        out = swiglu(ctx, {"wg": p["wg"], "wu": p["wu"], "wo": p["wo_mlp"]},
+                     h)
+        if cfg.post_norm:
+            out = rms_norm(out, p["ln2_post"], cfg.norm_eps)
+        return x + out
 
 
 def _make_attn_apply(window_of: Callable[[ModelConfig], Optional[int]]):
@@ -318,7 +330,8 @@ def lm_head(cfg: ModelConfig, params):
 
 def lm_logits(ctx: ModelCtx, params, x) -> jax.Array:
     """Logits for a few positions (serving) — NOT for full-seq training."""
-    return unembed(ctx, lm_head(ctx.cfg, params), x, transpose=True)
+    with jax.named_scope("lm_head"):
+        return unembed(ctx, lm_head(ctx.cfg, params), x, transpose=True)
 
 
 def loss_fn(ctx: ModelCtx, params, batch) -> jax.Array:

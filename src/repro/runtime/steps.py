@@ -8,11 +8,17 @@ Everything the launcher, dry-run, and tests need to jit a step:
 A StepBundle carries the python fn, abstract inputs, and in/out NamedShardings
 so ``jax.jit(fn, in_shardings=..., out_shardings=...).lower(*abstract)`` is
 one call (see launch/dryrun.py).
+
+The step bodies name their parts with ``jax.named_scope`` (``attn``,
+``mlp``, ``lm_head``, ``kv_gather``, ``kv_write``, ``kv_scatter``), which
+reaches only the compiled program's metadata; ``op_scopes`` maps a built
+step's compiled ops to those names, for reading a profiler trace.
 """
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,13 +43,115 @@ class StepBundle:
     accum_steps: int = 1      # microbatches folded into one optimizer step
     device_steps: int = 1     # optimizer steps folded into one dispatch
 
+    @property
+    def module_name(self) -> str:
+        """The compiled program's name, as a profiler trace shows it."""
+        return "jit_" + getattr(self.fn, "__name__", "fn")
+
     def jit(self):
+        if _BUILT.get(self.module_name) is not self:
+            _BUILT[self.module_name] = self
+            _SCOPES.pop(self.module_name, None)
+        return self._jit()
+
+    def _jit(self):
         return jax.jit(self.fn, in_shardings=self.in_shardings,
                        out_shardings=self.out_shardings,
                        donate_argnums=self.donate_argnums)
 
     def lower(self):
-        return self.jit().lower(*self.abstract_args)
+        return self._jit().lower(*self.abstract_args)
+
+
+# the newest bundle jitted in this process under each module name, and the
+# op -> scope maps of their compiled programs, made on request
+_BUILT: Dict[str, StepBundle] = {}
+_SCOPES: Dict[str, Dict[str, str]] = {}
+
+
+def op_scopes(module_name: str) -> Dict[str, str]:
+    """{HLO op name: scope path} for the compiled program of the step last
+    jitted in this process under ``module_name`` (``jit_paged_step``).
+
+    Every instruction of the program is a key, named as a profiler trace
+    names its ops (``fusion.3``, no ``%``).  Its path is the
+    ``named_scope`` names its metadata carries, outermost first, with
+    transforms unwrapped (``transpose(jvp(lm_head))`` is ``lm_head``):
+    ``paged_step/attn/kv_write``; empty where it carries none.  Made on
+    the first request by lowering and compiling the bundle again (a hit
+    in a persistent compilation cache), then kept: a reader of traces
+    calls it, never a step.  Raises KeyError for a name never jitted.
+
+    The persistent cache's key leaves metadata out by default, so a hit
+    could hand back a program compiled from another build's source, whose
+    op names are the same and whose scopes are not: this compile keys
+    the cache by the metadata too."""
+    if module_name not in _SCOPES:
+        bundle = _BUILT[module_name]
+        mesh = jax.tree.leaves(bundle.in_shardings)[0].mesh
+        flag = "jax_compilation_cache_include_metadata_in_key"
+        keyed = getattr(jax.config, flag)
+        jax.config.update(flag, True)
+        try:
+            with mesh:
+                text = bundle.lower().compile().as_text()
+        finally:
+            jax.config.update(flag, keyed)
+        _SCOPES[module_name] = hlo_scopes(text)
+    return _SCOPES[module_name]
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_WRAPPED = re.compile(r"[\w\-]+\((.*)\)")
+
+
+def _scope_path(op_name: str) -> str:
+    """``jit(f)/transpose(jvp(lm_head))/dot_general`` -> ``f/lm_head``:
+    the scopes of an op's metadata name, without the primitive."""
+    parts, depth, cur = [], 0, ""
+    for ch in op_name:
+        if ch == "/" and depth == 0:
+            parts.append(cur)
+            cur = ""
+            continue
+        depth += (ch == "(") - (ch == ")")
+        cur += ch
+    out = []
+    for part in parts:                   # the last piece is the primitive
+        m = _WRAPPED.fullmatch(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED.fullmatch(part)
+        out += [q for q in part.split("/") if q]
+    return "/".join(out)
+
+
+def hlo_scopes(text: str) -> Dict[str, str]:
+    """{instruction name: scope path} of a compiled module's text
+    (``Compiled.as_text()``).  An instruction whose metadata names no op
+    (a fusion, say) takes the first op name of the computation it
+    calls."""
+    comp_names: Dict[str, str] = {}       # computation -> first op_name
+    instrs: List[Tuple[str, str, str]] = []
+    comp = None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME.search(line)
+        calls = _CALLS.search(line)
+        name = op.group(1) if op else ""
+        if name and comp is not None:
+            comp_names.setdefault(comp, name)
+        instrs.append((m.group(1), name, calls.group(1) if calls else ""))
+    return {n: _scope_path(op or comp_names.get(calls, ""))
+            for n, op, calls in instrs}
 
 
 def _is_axes_leaf(x):
@@ -533,7 +641,8 @@ def paged_cache_view(pool, tables):
         g = leaf[:, tables]                       # (G, B, nb, bs, *tail)
         return g.reshape(g.shape[0], g.shape[1], g.shape[2] * g.shape[3],
                          *g.shape[4:])
-    return jax.tree.map(gather, pool)
+    with jax.named_scope("kv_gather"):
+        return jax.tree.map(gather, pool)
 
 
 def paged_cache_scatter(pool, views, tables, pos):
@@ -554,7 +663,8 @@ def paged_cache_scatter(pool, views, tables, pos):
         blk = jnp.where(pos < S, blk, 0)
         return pleaf.at[:, blk, pos % bs].set(rows.astype(pleaf.dtype))
 
-    return jax.tree.map(scat, pool, views)
+    with jax.named_scope("kv_scatter"):
+        return jax.tree.map(scat, pool, views)
 
 
 def paged_prompt_insert(pool, src, blocks):

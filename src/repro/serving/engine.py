@@ -27,6 +27,14 @@ Request lifecycle (see docs/architecture.md for the full diagram):
 
 The engine is deterministic given a queue and a clock; ``smoke``-size
 configs run it on CPU in seconds (tests/test_serving.py).
+
+Each loop iteration is timed by spans in the metrics registry (names in
+``report.SPANS``, meanings in docs/serving.md): ``serve/schedule`` around
+the host's policy work, with ``serve/prefill`` inside it for each
+admission, and ``serve/decode`` around the fused step, split into
+``serve/upload``, ``serve/launch`` and ``serve/readback``.  A decode step
+far slower than its recent median counts in ``serve/slow_steps``
+(``core.metrics.SlowSteps``).
 """
 from __future__ import annotations
 
@@ -39,9 +47,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, ParallelConfig, ShapeConfig
-from repro.core.metrics import Registry
+from repro.core.metrics import Registry, SlowSteps
 from repro.core.queue import WorkQueue
-from repro.serving.report import GAUGES, record_serving_totals
+from repro.serving.report import GAUGES, SPANS, record_serving_totals
 from repro.models import params as pr
 from repro.runtime import steps as steps_mod
 from repro.serving.pool import BlockPool
@@ -91,8 +99,12 @@ class ServingEngine:
         self.mesh = mesh
         self.num_slots = num_slots
         self.max_new_tokens = max_new_tokens
-        self.metrics = registry if registry is not None else Registry()
+        # spans ride the registry's clock: the engine's own, unless the
+        # caller brings a registry
+        self.metrics = (registry if registry is not None
+                        else Registry(clock=clock))
         self.clock = clock
+        self._slow = SlowSteps(self.metrics, GAUGES.SLOW_STEPS)
 
         S = prompt_len + max_new_tokens
         shape = ShapeConfig("serve", S, num_slots, "decode")
@@ -194,34 +206,43 @@ class ServingEngine:
         """Prefill one request alone and splice its cache into the slot
         (slotted) or its prompt blocks (paged).  Returns the first
         generated token."""
-        t0 = self.clock()
-        if self.paged:
-            blocks = self._tables[slot_index, :self._nb_prompt]
-            first, self._pool = self._paged_prefill(
-                self.params, self._pool,
-                jnp.asarray(self._pad_prompt(prompt)),
-                jnp.asarray(blocks), *self._extras)
-        else:
-            first, self._caches = self._prefill_insert(
-                self.params, self._caches,
-                jnp.asarray(self._pad_prompt(prompt)), jnp.int32(slot_index),
-                *self._extras)
-        first = int(first)
-        self.metrics.gauge(GAUGES.PREFILL_S, self.clock() - t0)
+        with self.metrics.span(SPANS.PREFILL) as span:
+            if self.paged:
+                blocks = self._tables[slot_index, :self._nb_prompt]
+                first, self._pool = self._paged_prefill(
+                    self.params, self._pool,
+                    jnp.asarray(self._pad_prompt(prompt)),
+                    jnp.asarray(blocks), *self._extras)
+            else:
+                first, self._caches = self._prefill_insert(
+                    self.params, self._caches,
+                    jnp.asarray(self._pad_prompt(prompt)),
+                    jnp.int32(slot_index), *self._extras)
+            first = int(first)
+        self.metrics.gauge(GAUGES.PREFILL_S, span.duration)
         return first
 
     def decode_step(self, tokens, positions) -> np.ndarray:
         """One fused greedy step over all slots.  ``tokens``/``positions``
         are per-slot (num_slots,) host lists; returns the new tokens."""
-        tok = jnp.asarray(np.asarray(tokens, np.int32)[:, None])
-        pos = jnp.asarray(np.asarray(positions, np.int32))
-        if self.paged:
-            out, self._pool = self._paged_decode(
-                self.params, self._pool, jnp.asarray(self._tables), tok, pos)
-        else:
-            out, self._caches = self._decode(self.params, self._caches,
-                                             tok, pos)
-        return np.asarray(out)[:, 0]
+        m = self.metrics
+        with m.span(SPANS.DECODE) as step:
+            before = self._slow.begin()
+            with m.span(SPANS.UPLOAD):
+                tok = jnp.asarray(np.asarray(tokens, np.int32)[:, None])
+                pos = jnp.asarray(np.asarray(positions, np.int32))
+                tables = jnp.asarray(self._tables) if self.paged else None
+            with m.span(SPANS.LAUNCH):
+                if self.paged:
+                    out, self._pool = self._paged_decode(
+                        self.params, self._pool, tables, tok, pos)
+                else:
+                    out, self._caches = self._decode(self.params,
+                                                     self._caches, tok, pos)
+            with m.span(SPANS.READBACK):
+                new = np.asarray(out)[:, 0]
+        self._slow.end(step, before)
+        return new
 
     def warmup(self) -> None:
         """Compile the jitted paths (prefill+insert, decode) off the
@@ -340,45 +361,48 @@ class ServingEngine:
             sched.on_release = self._on_slot_release
         t_start = self.clock()
         decode_s = 0.0
+        span = self.metrics.span
         with self.mesh:
             while True:
-                if should_stop is not None and should_stop():
-                    # preempted between steps: nack every in-flight slot
-                    # so a replacement engine re-serves them after one
-                    # decode step, not one visibility timeout
-                    self.metrics.inc(GAUGES.PREEMPTED)
-                    sched.release_all()
-                    break
-                for slot in sched.admit():
-                    # engine capacity bounds the stop length: past
-                    # prompt_pad+cap the cache has no row to write
-                    if slot.request.max_new_tokens > cap:
-                        slot.request = dataclasses.replace(
-                            slot.request, max_new_tokens=cap)
-                    if self.paged:
-                        self._admit_paged(sched, slot)
-                    else:
-                        first = self.prefill_into(slot.index,
-                                                  slot.request.prompt)
-                        sched.start(slot, first, self.prompt_pad)
-                if not sched.active():
-                    if sched.finished() and exit_on_drain:
+                with span(SPANS.SCHEDULE):
+                    if should_stop is not None and should_stop():
+                        # preempted between steps: nack every in-flight
+                        # slot so a replacement engine re-serves them
+                        # after one decode step, not one visibility timeout
+                        self.metrics.inc(GAUGES.PREEMPTED)
+                        sched.release_all()
                         break
-                    # queue momentarily empty — a long-lived replica
-                    # (exit_on_drain=False) idles here until its router
-                    # feeds it more work or stops it
-                    time.sleep(idle_wait)
-                    continue
-                if self.paged:
-                    self._ensure_paged_capacity(sched)
+                    for slot in sched.admit():
+                        # engine capacity bounds the stop length: past
+                        # prompt_pad+cap the cache has no row to write
+                        if slot.request.max_new_tokens > cap:
+                            slot.request = dataclasses.replace(
+                                slot.request, max_new_tokens=cap)
+                        if self.paged:
+                            self._admit_paged(sched, slot)
+                        else:
+                            first = self.prefill_into(slot.index,
+                                                      slot.request.prompt)
+                            sched.start(slot, first, self.prompt_pad)
                     if not sched.active():
+                        if sched.finished() and exit_on_drain:
+                            break
+                        # queue momentarily empty — a long-lived replica
+                        # (exit_on_drain=False) idles here until its
+                        # router feeds it more work or stops it
+                        time.sleep(idle_wait)
                         continue
+                    if self.paged:
+                        self._ensure_paged_capacity(sched)
+                        if not sched.active():
+                            continue
+                    tokens, positions = sched.last_tokens(), sched.positions()
                 t0 = self.clock()
-                toks = self.decode_step(sched.last_tokens(),
-                                        sched.positions())
+                toks = self.decode_step(tokens, positions)
                 decode_s += self.clock() - t0
-                sched.observe(toks)
-                sched.renew_leases()
+                with span(SPANS.SCHEDULE):
+                    sched.observe(toks)
+                    sched.renew_leases()
         wall = self.clock() - t_start
         results = sched.results()
         # useful throughput counts only acked completions; a stale-acked

@@ -43,6 +43,19 @@ class GAUGES:
     BLOCKS_IN_USE = "serve/blocks_in_use"
     REPLICAS = "serve/replicas"
     SCALE_EVENTS = "serve/scale_events"
+    SLOW_STEPS = "serve/slow_steps"
+
+
+class SPANS:
+    """The serving loop's span names (``Registry.span``; docs/serving.md).
+    None may equal a span name of the benchmark (``decode_step``,
+    ``prefill``), whose trace reduction labels idle time by them."""
+    SCHEDULE = "serve/schedule"
+    PREFILL = "serve/prefill"
+    DECODE = "serve/decode"
+    UPLOAD = "serve/upload"
+    LAUNCH = "serve/launch"
+    READBACK = "serve/readback"
 
 
 def make_requests(n_requests: int, prompt_len: int, gen: int, *,

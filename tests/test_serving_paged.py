@@ -495,3 +495,68 @@ def test_engine_timing_rides_the_injected_clock(serve_setup):
     assert sm["serve/ttft_s"]["last"] == 0.0
     assert sm["serve/request_latency_s"]["last"] == 0.0
     assert sm["serve/prefill_s"]["max"] == 0.0
+
+
+def test_engine_spans_each_loop_iteration(serve_setup):
+    """serve/schedule holds each admission's serve/prefill (whose span
+    the serve/prefill_s gauge reads); each decode step is a serve/decode
+    span split into upload -> launch -> readback."""
+    from repro.serving.report import SPANS
+    s = serve_setup
+    engine = mk_engine(s, paged=True, block_size=4, prefix_cache=False)
+    _, m = engine.run(WorkQueue(prompt_requests(s["cfg"], [4, 6, 3])))
+    prefills = m.spans(SPANS.PREFILL)
+    assert len(prefills) == 3
+    assert all(p.parent.name == SPANS.SCHEDULE for p in prefills)
+    assert [v for _, v in m.series(GAUGES.PREFILL_S).snapshot()] == \
+        [p.duration for p in prefills]
+    decodes = m.spans(SPANS.DECODE)
+    assert len(decodes) == m.series(GAUGES.DECODE_STEPS).total > 0
+    kids = [sp for sp in m.spans() if sp.parent is not None
+            and sp.parent.name == SPANS.DECODE]
+    assert len(kids) == 3 * len(decodes)
+    for step in decodes:
+        up, launch, back = [k for k in kids if k.parent is step]
+        assert [up.name, launch.name, back.name] == [
+            SPANS.UPLOAD, SPANS.LAUNCH, SPANS.READBACK]
+        assert step.start <= up.start <= up.end <= launch.start \
+            <= launch.end <= back.start <= back.end <= step.end
+    assert all(sp.parent is None for sp in m.spans(SPANS.SCHEDULE))
+    assert not set(SPANS.__dict__.values()) & {"decode_step", "prefill",
+                                                "train_dispatch"}
+
+
+def test_forced_slow_step_counts_and_carries_notes(serve_setup):
+    """A decode step that the (injected) clock makes far slower than the
+    running median counts in serve/slow_steps, and its span carries the
+    process's context switches, CPU and garbage-collection time."""
+    import gc
+    import time as _time
+
+    from repro.core.metrics import SLOW_MIN_STEPS
+    from repro.serving.report import SPANS
+    s = serve_setup
+    skew = [0.0]
+    reg = Registry(clock=lambda: _time.monotonic() + skew[0])
+    engine = mk_engine(s, paged=True, block_size=4, prefix_cache=False,
+                       num_slots=1, max_new_tokens=16, registry=reg)
+    decode, calls = engine._paged_decode, [0]
+    stall_at = SLOW_MIN_STEPS + 3
+
+    def stalled(*a):
+        calls[0] += 1
+        if calls[0] == stall_at:
+            skew[0] += 100.0
+            gc.collect()
+        return decode(*a)
+
+    engine._paged_decode = stalled
+    engine.run(WorkQueue(prompt_requests(s["cfg"], [16])))
+    steps = reg.spans(SPANS.DECODE)
+    assert len(steps) > stall_at
+    stall = steps[stall_at - 1]
+    assert stall.duration >= 100.0
+    assert reg.series(GAUGES.SLOW_STEPS).total >= 1
+    assert {"median_s", "invol_switches", "cpu_s", "gc_s"} <= \
+        set(stall.attrs)
+    assert stall.attrs["gc_s"] > 0 and stall.attrs["median_s"] < 100.0
