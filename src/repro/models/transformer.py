@@ -108,6 +108,29 @@ def insert_kv(cache, k, v, pos):
                 jnp.where(hit, v, cache["v"]))
 
 
+def paged_layer_view(pool, group, tables):
+    """Layer group ``group``'s contiguous (B, S, ...) views of a paged
+    pool, whose leaves are (groups, num_blocks, block_size, ...):
+    ``tables`` (B, S // block_size) lists every slot's blocks in order.
+    One gather per leaf, indexed by group and block together, so the
+    group's slice of the pool is never copied out first."""
+    def gather(leaf):
+        g = leaf[group, tables]                   # (B, nb, bs, *tail)
+        return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+    with jax.named_scope("kv_gather"):
+        return jax.tree.map(gather, pool)
+
+
+def written_rows(views, pos):
+    """The row each slot's decode step wrote into its (B, S, ...) view,
+    (B, ...) per leaf: slot i's row ``pos[i]``, clamped to S-1 where the
+    slot wrote nothing (the write-back sends those rows to the null
+    block)."""
+    with jax.named_scope("kv_scatter"):
+        return jax.tree.map(
+            lambda leaf: leaf[jnp.arange(leaf.shape[0]), pos], views)
+
+
 def _tp_boundary(ctx: ModelCtx, h, mode: str, tag: str):
     """Make the Megatron-SP all-gather an explicit, NAMED value so the
     remat policy (save_only_these_names) can keep it for backward instead
@@ -235,8 +258,14 @@ def cache_schema(cfg: ModelConfig, B: int, S: int) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
-                 extras):
-    """Scan (or unrolled loop) over layer groups; returns (x, new_caches)."""
+                 extras, tables=None):
+    """Scan (or unrolled loop) over layer groups; returns (x, new_caches).
+
+    With ``tables`` (paged decode) ``caches`` is the block pool and the
+    scan runs over group indices: each group gathers its own layers' views
+    from the pool, and its scan output is only the row each slot wrote,
+    (G, B, ...) per leaf.  (A group's slice of the pool taken as ``xs``
+    is copied out before the gather reads it.)"""
     cfg, par = ctx.cfg, ctx.par
     shared = params.get("shared_attn")
     blocks = params["blocks"]
@@ -264,6 +293,8 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
     def body(carry, xs):
         x, aux = carry
         gp, gc = xs
+        if tables is not None:          # gc is the group's index
+            gc = paged_layer_view(caches, gc, tables)
         new_gc = {}
         for i, kind in enumerate(cfg.block_pattern):
             key = f"{i}_{kind}"
@@ -274,22 +305,26 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
             if mode == "train" and par.sequence_parallel:
                 # saved per-layer inputs stay seq-sharded under remat
                 x = ctx.cons(x, ("batch", "act_seq_sharded", None))
+        if tables is not None:
+            new_gc = written_rows(new_gc, pos)
         return (x, aux), new_gc
 
     if mode == "train" and par.remat:
         body = jax.checkpoint(body, prevent_cse=False, policy=policy)
 
     aux0 = jnp.zeros((), jnp.float32)
+    G = cfg.num_groups
+    per_group = caches if tables is None else jnp.arange(G)
     if par.scan_layers:
-        xs = (blocks, caches)
+        xs = (blocks, per_group)
         (x, aux), new_caches = jax.lax.scan(body, (x, aux0), xs)
     else:
-        G = cfg.num_groups
         ncs = []
         aux = aux0
         for gi in range(G):
             gp = jax.tree.map(lambda a: a[gi], blocks)
-            gc = None if caches is None else jax.tree.map(lambda a: a[gi], caches)
+            gc = (None if per_group is None
+                  else jax.tree.map(lambda a: a[gi], per_group))
             (x, aux), nc = body((x, aux), (gp, gc))
             ncs.append(nc)
         new_caches = (jax.tree.map(lambda *a: jnp.stack(a), *ncs)
@@ -298,11 +333,14 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
 
 
 def forward(ctx: ModelCtx, params, tokens, *, mode: str = "train",
-            caches=None, pos=None, extras=None):
+            caches=None, pos=None, extras=None, tables=None):
     """tokens (B,St) int32.  mode train|prefill: St=S; decode: St=1.
 
     Returns (final hidden states (B,St,D), new_caches, aux_loss) — callers
     pick the head: chunked xent for training, last-token logits for serving.
+    Decode with block ``tables`` (B, S // block_size) reads a paged pool
+    as ``caches`` and returns, as ``new_caches``, only the rows written
+    (layers, B, ...) for the caller to scatter into the pool.
     """
     cfg = ctx.cfg
     x = embed_tokens(ctx, params["embed"], tokens)
@@ -317,7 +355,7 @@ def forward(ctx: ModelCtx, params, tokens, *, mode: str = "train",
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     x, new_caches, aux = _scan_groups(ctx, params, x, mode=mode,
                                       positions=positions, caches=caches,
-                                      pos=pos, extras=extras)
+                                      pos=pos, extras=extras, tables=tables)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     x = ctx.cons(x, ("batch", "act_seq_sharded" if mode == "train"
                      and ctx.par.sequence_parallel else "seq", None))

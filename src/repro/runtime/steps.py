@@ -594,7 +594,7 @@ def cache_batch_evict(dst, slot):
 
 
 # ---------------------------------------------------------------------------
-# paged KV pool: block tables + gather/scatter decode addressing
+# paged KV pool: block tables + per-layer gather / one-row scatter decode
 # ---------------------------------------------------------------------------
 # The slotted cache above dedicates S positions to every slot whether the
 # request uses them or not; the paged layout replaces axis 1 (slots) with a
@@ -633,38 +633,23 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
     return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), abstract)
 
 
-def paged_cache_view(pool, tables):
-    """Gather each slot's blocks into a contiguous (layers, B, S, ...) view
-    value-identical to the slotted cache — the decode forward runs on it
-    unchanged.  ``tables`` is (B, S // block_size) int32 block ids."""
-    def gather(leaf):
-        g = leaf[:, tables]                       # (G, B, nb, bs, *tail)
-        return g.reshape(g.shape[0], g.shape[1], g.shape[2] * g.shape[3],
-                         *g.shape[4:])
-    with jax.named_scope("kv_gather"):
-        return jax.tree.map(gather, pool)
+def paged_cache_scatter(pool, rows, tables, pos):
+    """Write back the one row per slot and layer that the decode step wrote.
 
-
-def paged_cache_scatter(pool, views, tables, pos):
-    """Write back the one row per slot that the decode step mutated.
-
-    ``views`` is the post-forward gathered cache; slot i wrote position
-    ``pos[i]``.  Rows whose position is out of range (free slots parked at
-    0 with an all-null table, or finished slots past S-1) land on the null
-    block, where duplicate writes are harmless by the masking argument
-    above."""
-    B = tables.shape[0]
-
-    def scat(pleaf, vleaf):
+    ``rows`` leaves are (layers, B, ...): slot i wrote position ``pos[i]``
+    (``transformer.written_rows``).  Rows whose position is out of range
+    (free slots parked at 0 with an all-null table, or finished slots past
+    S-1) land on the null block, where duplicate writes are harmless by
+    the masking argument above."""
+    def scat(pleaf, rleaf):
         bs = pleaf.shape[2]
-        S = vleaf.shape[2]
-        rows = vleaf[:, jnp.arange(B), pos]       # (G, B, *tail)
+        S = tables.shape[1] * bs
         blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
         blk = jnp.where(pos < S, blk, 0)
-        return pleaf.at[:, blk, pos % bs].set(rows.astype(pleaf.dtype))
+        return pleaf.at[:, blk, pos % bs].set(rleaf.astype(pleaf.dtype))
 
     with jax.named_scope("kv_scatter"):
-        return jax.tree.map(scat, pool, views)
+        return jax.tree.map(scat, pool, rows)
 
 
 def paged_prompt_insert(pool, src, blocks):
@@ -683,10 +668,14 @@ def paged_prompt_insert(pool, src, blocks):
 def build_paged_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
                        shape: ShapeConfig, *, block_size: int,
                        num_blocks: int) -> StepBundle:
-    """One fused per-slot decode step against the paged pool:
-    gather block-table views -> identical forward -> scatter the written
-    row back.  Signature: (params, pool, tables, token, pos) ->
-    (next_token, pool); donate the pool for in-place updates."""
+    """One fused per-slot decode step against the paged pool.  Inside the
+    layer scan each layer gathers its own (B, S, ...) views from the pool
+    through the block tables (``transformer.paged_layer_view``), runs the
+    slotted layer on them unchanged, and hands out only the row each slot
+    wrote; one scatter after the scan writes those rows into the pool.
+    No views of the whole pool are built or stacked.  Signature: (params,
+    pool, tables, token, pos) -> (next_token, pool); donate the pool for
+    in-place updates."""
     cfg = resolve_cfg(cfg, shape)
     mod = _model_module(cfg)
     ctx = ModelCtx(cfg, par, mesh)
@@ -717,12 +706,11 @@ def build_paged_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
     repl = NamedSharding(mesh, P())
 
     def paged_step(params, pool, tables, token, pos):
-        views = paged_cache_view(pool, tables)
-        hidden, new_views, _ = mod.forward(ctx, params, token, mode="decode",
-                                           caches=views, pos=pos)
+        hidden, rows, _ = mod.forward(ctx, params, token, mode="decode",
+                                      caches=pool, pos=pos, tables=tables)
         logits = mod.lm_logits(ctx, params, hidden)
         next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        pool = paged_cache_scatter(pool, new_views, tables, pos)
+        pool = paged_cache_scatter(pool, rows, tables, pos)
         return next_tok[:, None], pool
 
     return StepBundle(

@@ -1,9 +1,10 @@
 """Host-side block-pool bookkeeping for the paged KV cache.
 
-The device side (runtime.steps: ``init_paged_cache`` / ``paged_cache_view``
-/ ``paged_cache_scatter``) is pure data movement; everything *policy* —
-which block belongs to whom, when it can be reused — lives here, mirroring
-the scheduler/engine split.
+The device side (runtime.steps: ``init_paged_cache``, the decode step's
+per-layer gather ``models.transformer.paged_layer_view`` and its one-row
+write-back ``paged_cache_scatter``) is pure data movement; everything
+*policy* — which block belongs to whom, when it can be reused — lives
+here, mirroring the scheduler/engine split.
 
 Blocks are refcounted.  Block 0 is reserved as the NULL block (table
 entries for unallocated tail positions point at it; its garbage content is

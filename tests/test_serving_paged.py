@@ -409,6 +409,155 @@ def test_paged_decode_bit_identical_to_slotted(serve_setup):
     assert m.summary()["serve/tokens_generated"]["total"] == sum(gens)
 
 
+# paged decode step against the whole-view formulation it replaced
+
+S_STEP, BS_STEP, NB_POOL = 16, 4, 12     # 4 blocks per slot, 12 in the pool
+
+
+def _step_cfg(s, arch):
+    """A smoke config with more than one layer group; the windowed kind's
+    window is shorter than the cache, so its mask cuts history."""
+    import dataclasses
+
+    from repro.configs import registry as cfg_registry
+    cfg = cfg_registry.get_smoke(arch)
+    cfg = cfg.replace(num_layers=3 * len(cfg.block_pattern))
+    if "local" in cfg.block_pattern:
+        cfg = cfg.replace(attn=dataclasses.replace(cfg.attn, window=5))
+    return cfg
+
+
+def _whole_view_step(cfg, par, mesh):
+    """The paged decode step as it was before each layer gathered its own
+    view: gather every layer's (layers, B, S, ...) view before the layer
+    scan, run the forward on it, then scatter each slot's written row."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import transformer as tfm
+    from repro.models.layers import ModelCtx
+    ctx = ModelCtx(cfg, par, mesh)
+
+    def step(params, pool, tables, token, pos):
+        def gather(leaf):
+            g = leaf[:, tables]                   # (G, B, nb, bs, *tail)
+            return g.reshape(g.shape[0], g.shape[1], g.shape[2] * g.shape[3],
+                             *g.shape[4:])
+
+        views = jax.tree.map(gather, pool)
+        hidden, new_views, _ = tfm.forward(ctx, params, token, mode="decode",
+                                           caches=views, pos=pos)
+        logits = tfm.lm_logits(ctx, params, hidden)
+        next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        B = tables.shape[0]
+
+        def scat(pleaf, vleaf):
+            bs, S = pleaf.shape[2], vleaf.shape[2]
+            rows = vleaf[:, jnp.arange(B), pos]   # (G, B, *tail)
+            blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
+                                      axis=1)[:, 0]
+            blk = jnp.where(pos < S, blk, 0)
+            return pleaf.at[:, blk, pos % bs].set(rows.astype(pleaf.dtype))
+
+        return next_tok[:, None], jax.tree.map(scat, pool, new_views)
+
+    return jax.jit(step)
+
+
+def _paged_step(cfg, s, B):
+    from repro.configs.base import ShapeConfig
+    from repro.runtime import steps as steps_mod
+    return steps_mod.build_paged_decode(
+        cfg, s["par"], s["mesh"], ShapeConfig("serve", S_STEP, B, "decode"),
+        block_size=BS_STEP, num_blocks=NB_POOL)
+
+
+def _bits(tree):
+    import jax
+    return [np.asarray(a).view(np.uint16) for a in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("arch, positions, scan", [
+    ("phi4-mini-3.8b", [0, 7, 13], True),    # slot 0 parked: all-null table
+    ("phi4-mini-3.8b", [S_STEP + 1, 5, 11], True),  # slot 0 past S-1
+    ("phi4-mini-3.8b", [8, 4, 15], True),    # first rows of blocks 2 and 1
+    ("gemma2-9b", [9, 14, 3], True),         # local + global, window 5
+    ("phi4-mini-3.8b", [0, 6, S_STEP], False),  # layers unrolled, no scan
+], ids=["parked", "past_end", "block_boundary", "local", "unrolled"])
+def test_paged_step_matches_whole_view_step(serve_setup, arch, positions,
+                                            scan):
+    """On a random pool and block tables, the step that gathers each
+    layer's view inside the layer scan and writes back only the new rows
+    gives the tokens and the pool, bit for bit, of the step that gathered
+    the whole pool's view first."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import params as pr
+    from repro.models import transformer as tfm
+    from repro.runtime import steps as steps_mod
+    s = dict(serve_setup,
+             par=dataclasses.replace(serve_setup["par"], scan_layers=scan))
+    cfg = _step_cfg(s, arch)
+    B, nb = len(positions), S_STEP // BS_STEP
+    rng = np.random.RandomState(sum(positions))
+    params = pr.init_params(tfm.lm_schema(cfg), jax.random.key(1),
+                            cfg.param_dtype)
+    pool = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        steps_mod.init_paged_cache(cfg, NB_POOL, BS_STEP))
+    # each slot owns the blocks up to its write position; the tail is null
+    owned = rng.permutation(np.arange(1, NB_POOL))
+    tables = np.zeros((B, nb), np.int32)
+    for i, p in enumerate(positions):
+        if i == 0 and p == 0:
+            continue                              # parked free slot
+        n = min(p // BS_STEP + 1, nb)
+        tables[i, :n] = owned[:n]
+        owned = owned[n:]
+    token = jnp.asarray(rng.randint(1, cfg.vocab_size, (B, 1)), jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    tables = jnp.asarray(tables)
+
+    before = _bits(pool)
+    want_tok, want_pool = _whole_view_step(cfg, s["par"], s["mesh"])(
+        params, pool, tables, token, pos)
+    want = _bits(want_pool)
+    got_tok, got_pool = _paged_step(cfg, s, B).jit()(   # donates the pool
+        params, pool, tables, token, pos)
+    np.testing.assert_array_equal(np.asarray(got_tok), np.asarray(want_tok))
+    for g, w, b in zip(_bits(got_pool), want, before):
+        np.testing.assert_array_equal(g, w)
+        assert not np.array_equal(w, b)          # the step wrote rows
+
+
+def test_paged_step_holds_no_whole_pool_view(serve_setup):
+    """The compiled paged step holds one layer's (B, S, KV, dh) view at a
+    time: no (layers, B, S, KV, dh) stack of views and no whole-pool
+    gather, (B * nb, layers, bs, KV, dh) or (layers, B, nb, bs, KV, dh).
+    The whole-view step shows them, so the shapes are the right ones to
+    look for."""
+    import re
+    s = serve_setup
+    cfg = _step_cfg(s, "phi4-mini-3.8b")
+    G, B, nb = cfg.num_groups, 2, S_STEP // BS_STEP
+    KV, dh = cfg.num_kv_heads, cfg.resolved_head_dim
+    whole = [(G, B, S_STEP, KV, dh), (B * nb, G, BS_STEP, KV, dh),
+             (G, B, nb, BS_STEP, KV, dh)]
+    pattern = "|".join(re.escape("[" + ",".join(map(str, sh)) + "]")
+                       for sh in whole)
+    layer_view = re.escape(f"[{B},{S_STEP},{KV},{dh}]")
+    bundle = _paged_step(cfg, s, B)
+    text = bundle.lower().compile().as_text()
+    assert not re.findall(pattern, text)
+    assert re.search(layer_view, text)
+    old = _whole_view_step(cfg, s["par"], s["mesh"]).lower(
+        *bundle.abstract_args).compile().as_text()
+    assert re.search(pattern, old)
+
+
 def test_paged_prefix_reuse_hits_and_refcounts(serve_setup):
     """Identical prompts through one slot: the first request prefills and
     caches its prompt blocks, every later one retains them (hit) and

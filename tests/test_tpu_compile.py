@@ -1,4 +1,5 @@
-"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+"""Compile the main path's Pallas kernels, and the paged decode step, for
+a described TPU v5e chip.
 
 Nothing runs here: the TPU compiler installed with jax compiles for a
 chip that is described, not attached, so these tests catch what interpret
@@ -126,3 +127,39 @@ def test_adamw_sharded_compiles_per_shard(mesh4, monkeypatch):
         state).compile()
     assert _mosaic_calls(compiled) == 2
     assert shd["wg"].spec != P()
+
+
+def test_paged_decode_reads_the_pool_in_place(topo):
+    """The paged decode step at phi4-mini's widths (two layers, 18 slots x
+    1,024 positions in blocks of 8): each layer gathers its views straight
+    from the pool, with no copy of the layer's pool slice first, no
+    whole-pool view, and temporaries under one layer's K and V views."""
+    import re
+
+    import numpy as np
+
+    from repro.configs import registry
+    from repro.configs.base import ShapeConfig
+    from repro.runtime import steps as steps_mod
+
+    L, slots, S, bs = 2, 18, 1024, 8
+    cfg = registry.get_config("phi4-mini-3.8b").replace(num_layers=L)
+    nb = 1 + slots * S // bs + 32
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    bundle = steps_mod.build_paged_decode(
+        cfg, registry.get_parallel("phi4-mini-3.8b"), mesh,
+        ShapeConfig("serve", S, slots, "decode"), block_size=bs,
+        num_blocks=nb)
+    args = [jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s), a, s)
+            for a, s in zip(bundle.abstract_args, bundle.in_shardings)]
+    compiled = jax.jit(
+        bundle.fn, in_shardings=bundle.in_shardings,
+        out_shardings=bundle.out_shardings,
+        donate_argnums=bundle.donate_argnums).lower(*args).compile()
+    text = compiled.as_text()
+    KV, dh = cfg.num_kv_heads, cfg.resolved_head_dim
+    assert not re.search(rf"\[{nb},{bs},{KV},{dh}\]", text)   # slice copy
+    assert not re.search(rf"\[{slots * S // bs},{L},{bs},{KV},{dh}\]|"
+                         rf"\[{L},{slots},{S},{KV},{dh}\]", text)
+    view = slots * S * KV * dh * 2                            # bf16 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * view
