@@ -1,0 +1,7 @@
+"""``stall_share.decode``'s reading in Kimi K2's cell: a name of its own
+because that entry's list of cells is pinned by bench/tests/test_scopes.py."""
+from bench import spec
+
+
+def read(run, ctx):
+    return spec.module("metrics", "stall_share.decode").read(run, ctx)

@@ -12,6 +12,20 @@ from typing import Any, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (``rope_scaling`` of type yarn): frequencies
+    between the two correction dims blend from interpolated (divided by
+    ``factor``) to the originals, and the softmax scale gains
+    ``mscale_all_dim``'s factor squared (DeepSeek-V3's attention)."""
+    factor: float = 32.0
+    beta_fast: float = 1.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+    original_max_position_embeddings: int = 4096
+
+
+@dataclass(frozen=True)
 class AttnConfig:
     """Self-attention variant knobs."""
     rope_theta: float = 10_000.0
@@ -19,6 +33,21 @@ class AttnConfig:
     logit_softcap: Optional[float] = None  # attn-score softcap (gemma2: 50.0)
     qkv_bias: bool = False                 # qwen-family bias on q/k/v
     use_rope: bool = True                  # whisper uses learned/sinusoidal
+    rope_scaling: Optional[YarnConfig] = None
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3, Kimi K2): queries
+    through a low-rank ``q_lora_rank`` bottleneck, keys and values from
+    one shared ``kv_lora_rank`` latent per token plus a shared
+    ``qk_rope_head_dim`` rotary key; the decode cache holds only those
+    two per token."""
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
 
 
 @dataclass(frozen=True)
@@ -29,6 +58,16 @@ class MoEConfig:
     router_aux_weight: float = 0.01
     # tokens are routed with an all_to_all over this logical axis
     expert_axis: str = "expert"
+    # --- the held-expert layer (kind "mla_moe"; DeepSeek-V3 routing) ---
+    expert_ff: int = 0                # width of one expert; 0 -> d_ff
+    num_shared_experts: int = 0       # always-on experts, width expert_ff each
+    routed_scaling: float = 1.0       # gate weights x this after normalising
+    # (first expert, count) of the experts this chip holds; None -> all
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.num_experts)
 
 
 @dataclass(frozen=True)
@@ -59,10 +98,16 @@ class ModelConfig:
     vocab_size: int = 256
     head_dim: int = 0         # 0 -> d_model // num_heads
     # One scanned "layer group" applies this pattern of block kinds in order.
-    # num_layers must equal len(block_pattern) * num_groups.
+    # num_layers must equal
+    #   len(lead_pattern) + len(block_pattern) * num_groups.
     # kinds: attn | local | global | moe | mamba | mamba_attn | rwkv | cross
+    #        | mla_dense | mla_moe
     block_pattern: Tuple[str, ...] = ("attn",)
+    # layers applied once, in order, before the scanned groups (DeepSeek-V3's
+    # first_k_dense_replace leading dense layers)
+    lead_pattern: Tuple[str, ...] = ()
     attn: AttnConfig = field(default_factory=AttnConfig)
+    mla: Optional[MLAConfig] = None
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     rwkv: Optional[RWKVConfig] = None
@@ -88,9 +133,10 @@ class ModelConfig:
 
     @property
     def num_groups(self) -> int:
-        assert self.num_layers % len(self.block_pattern) == 0, (
-            self.name, self.num_layers, self.block_pattern)
-        return self.num_layers // len(self.block_pattern)
+        scanned = self.num_layers - len(self.lead_pattern)
+        assert scanned % len(self.block_pattern) == 0, (
+            self.name, self.num_layers, self.lead_pattern, self.block_pattern)
+        return scanned // len(self.block_pattern)
 
     @property
     def q_per_kv(self) -> int:
@@ -195,7 +241,7 @@ LONG_CONTEXT_ARCHS = ("zamba2-2.7b", "rwkv6-1.6b")
 def smoke_config(cfg: ModelConfig) -> ModelConfig:
     """A tiny same-family config for CPU smoke tests (1 fwd/train step)."""
     kw: dict[str, Any] = dict(
-        num_layers=len(cfg.block_pattern),
+        num_layers=len(cfg.lead_pattern) + len(cfg.block_pattern),
         d_model=64,
         num_heads=4,
         num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads else 0,
@@ -204,7 +250,13 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         head_dim=16,
     )
     if cfg.moe is not None:
-        kw["moe"] = dataclasses.replace(cfg.moe, num_experts=4, top_k=2)
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, num_experts=4, top_k=2,
+            expert_ff=32 if cfg.moe.expert_ff else 0)
+    if cfg.mla is not None:
+        kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
+                              qk_nope_head_dim=16, qk_rope_head_dim=8,
+                              v_head_dim=16)
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(cfg.ssm, state_dim=16, head_dim=16, chunk=8)
     if cfg.rwkv is not None:

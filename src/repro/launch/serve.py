@@ -133,7 +133,7 @@ def serve_static(arch: str, *, smoke: bool, n_requests: int, prompt_len: int,
     with mesh:
         if warmup:
             dummy = jnp.ones((batch, T), jnp.int32)
-            last, small = prefill(params, dummy, *extras)
+            last, small, _ = prefill(params, dummy, *extras)
             caches = pad_cache(steps_mod.init_cache(cfg, batch, S), small)
             tok = jnp.argmax(last, -1).astype(jnp.int32)[:, None]
             decode(params, caches, tok, jnp.int32(T))
@@ -163,7 +163,7 @@ def serve_static(arch: str, *, smoke: bool, n_requests: int, prompt_len: int,
 
             # ---- prefill -> first token + cache
             t0 = time.perf_counter()
-            last, small = prefill(params, jnp.asarray(prompts), *extras)
+            last, small, _ = prefill(params, jnp.asarray(prompts), *extras)
             caches = pad_cache(steps_mod.init_cache(cfg, batch, S), small)
             tok = jnp.argmax(last, -1).astype(jnp.int32)[:, None]
             metrics.gauge(GAUGES.PREFILL_S, time.perf_counter() - t0)

@@ -74,7 +74,8 @@ def _qchunk_attention(q, k, v, *, scale, window, cap, chunk, causal=True):
     """Scan over q chunks; q seq dim unsharded ("heads" strategy).
 
     The per-chunk fn is checkpointed so backward recomputes each chunk's
-    probabilities instead of saving (Sq, Sk)-worth of residuals.
+    probabilities instead of saving (Sq, Sk)-worth of residuals.  v's
+    head size may differ from q's and k's (MLA: 192 against 128).
     """
     B, Sq, KV, g, dh = q.shape
     Sk = k.shape[1]
@@ -98,7 +99,7 @@ def _qchunk_attention(q, k, v, *, scale, window, cap, chunk, causal=True):
     if nq == 1:
         return one(jnp.int32(0))
     _, ys = jax.lax.scan(lambda c, i: (c, one(i)), None, jnp.arange(nq))
-    return jnp.moveaxis(ys, 0, 1).reshape(B, Sq, KV, g, dh)
+    return jnp.moveaxis(ys, 0, 1).reshape(B, Sq, KV, g, v.shape[-1])
 
 
 def _full_attention(q, k, v, *, scale, window, cap, causal=True):

@@ -90,9 +90,9 @@ def _self_block(ctx, p, x, *, causal, mode="train", cache=None, pos=None):
     new_cache = {}
     if mode == "decode":
         from repro.models.transformer import insert_kv
-        k_cache, v_cache = insert_kv(cache, k, v, pos)
-        out = attn_mod.decode_attention(ctx, q, k_cache, v_cache, pos)
-        new_cache = {"k": k_cache, "v": v_cache}
+        new_cache = insert_kv(cache, {"k": k, "v": v}, pos)
+        out = attn_mod.decode_attention(ctx, q, new_cache["k"],
+                                        new_cache["v"], pos)
     else:
         out = attn_mod.causal_attention(ctx, q, k, v, strategy=strategy,
                                         mode=mode, causal=causal)

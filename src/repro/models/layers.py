@@ -49,11 +49,14 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return x * inv * (1.0 + scale.astype(x.dtype))
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding.  x: (B, S, N, dh); positions: (S,) or (B, S)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: Optional[jax.Array] = None) -> jax.Array:
+    """Rotary embedding.  x: (B, S, N, dh); positions: (S,) or (B, S).
+    ``inv_freq`` (dh // 2,) replaces theta's frequencies (yarn)."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)  # (half,)
+    freqs = (theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+             if inv_freq is None else inv_freq)                     # (half,)
     if positions.ndim == 1:
         ang = positions[None, :, None].astype(jnp.float32) * freqs  # (1,S,half)
     else:

@@ -14,6 +14,10 @@ The routed-MLP pipeline per model-axis rank (megablocks/MaxText-style):
 Capacity-dropped tokens fall back to identity (standard Switch behavior);
 the load-balance aux loss (Shazeer et al.) discourages the imbalance that
 causes drops.
+
+The held-expert layer at the end (``held_expert_mlp``, kind ``mla_moe``)
+is the dropless one: DeepSeek-V3's sigmoid router over all experts, one
+chip's held experts computed exactly, and a shared expert.
 """
 from __future__ import annotations
 
@@ -238,3 +242,81 @@ def apply_moe_block(ctx, p, x, *, mode, positions, cache, pos, shared, extras):
     if cfg.post_norm:
         out = rms_norm(out, p["ln2_post"], cfg.norm_eps)
     return x + out, new_cache, aux
+
+
+# ---------------------------------------------------------------------------
+# the held-expert layer (kind "mla_moe": DeepSeek-V3 / Kimi K2 routing)
+# ---------------------------------------------------------------------------
+# One chip's share of an expert-parallel layer: the router keeps its full
+# width (all E experts) and its experts per token, and the chip computes
+# the part of the result that the experts it holds give, exactly: each of
+# its tokens through each held expert, weighted by that token's gate for
+# it (zero where the token chose another).  Nothing is dropped.  The other
+# chips' parts are theirs: on one chip there is no exchange.
+
+def held_expert_schema(cfg: ModelConfig, G: int) -> Dict[str, PSpec]:
+    m, D = cfg.moe, cfg.d_model
+    F = m.expert_ff or cfg.d_ff
+    Fs = F * m.num_shared_experts
+    _, n = m.held_range
+    s = {
+        "router": PSpec((G, D, m.num_experts), ("layers", "fsdp", None),
+                        scale=D ** -0.5),
+        # the correction bias (noaux_tc): used for the choice only
+        "router_bias": PSpec((G, m.num_experts), ("layers", None), "zeros",
+                             dtype="float32"),
+        "moe_wg": PSpec((G, n, D, F), ("layers", "expert", "fsdp", None)),
+        "moe_wu": PSpec((G, n, D, F), ("layers", "expert", "fsdp", None)),
+        "moe_wo": PSpec((G, n, F, D), ("layers", "expert", None, "fsdp")),
+    }
+    if Fs:
+        s["sh_wg"] = PSpec((G, D, Fs), ("layers", "fsdp", "tp_ff"))
+        s["sh_wu"] = PSpec((G, D, Fs), ("layers", "fsdp", "tp_ff"))
+        s["sh_wo"] = PSpec((G, Fs, D), ("layers", "tp_ff", "fsdp"))
+    return s
+
+
+def sigmoid_route(logits: jax.Array, bias: jax.Array, top_k: int,
+                  scaling: float):
+    """DeepSeek-V3's noaux_tc gate on float32 ``logits`` (..., E): the
+    ``top_k`` experts by sigmoid score plus ``bias``; their weights are
+    the unbiased scores, normalised to sum to one, times ``scaling``.
+    Returns (ids, weights), each (..., top_k)."""
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias, top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return ids, w * scaling
+
+
+def held_expert_mlp(ctx: ModelCtx, p, x: jax.Array):
+    """x (B, T, D) -> (this chip's routed part plus the shared expert,
+    (B, T, D); the number of held experts that some token chose; the
+    router's choice, (B, T, top_k) expert ids)."""
+    cfg = ctx.cfg
+    m = cfg.moe
+    cd = ctx.compute_dtype
+    first, n = m.held_range
+    with jax.named_scope("router"):
+        logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        ids, w = sigmoid_route(logits, p["router_bias"].astype(jnp.float32),
+                               m.top_k, m.routed_scaling)
+        chose = ids[..., None] == first + jnp.arange(n)       # (B,T,k,n)
+        gate = jnp.sum(jnp.where(chose, w[..., None], 0.0), axis=-2)
+        hits = jnp.sum(jnp.any(chose, axis=(0, 1, 2))).astype(jnp.float32)
+    with jax.named_scope("experts"):
+        g = jnp.einsum("btd,edf->btef", x, p["moe_wg"].astype(cd))
+        u = jnp.einsum("btd,edf->btef", x, p["moe_wu"].astype(cd))
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(cd) * u
+        y = jnp.einsum("btef,efd->bted", h, p["moe_wo"].astype(cd))
+        out = jnp.einsum("bted,bte->btd", y.astype(jnp.float32), gate)
+    if "sh_wg" in p:
+        with jax.named_scope("shared_expert"):
+            g = jnp.einsum("btd,df->btf", x, p["sh_wg"].astype(cd))
+            u = jnp.einsum("btd,df->btf", x, p["sh_wu"].astype(cd))
+            h = jax.nn.silu(g.astype(jnp.float32)).astype(cd) * u
+            out = out + jnp.einsum("btf,fd->btd", h,
+                                   p["sh_wo"].astype(cd)).astype(jnp.float32)
+    return out.astype(cd), hits, ids

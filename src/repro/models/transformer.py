@@ -5,8 +5,16 @@ llama-3.2-vision: ("attn",)*4 + ("cross",)); parameters for each position are
 stacked along a leading group axis and the model scans over groups — the HLO
 is depth-independent, which keeps 512-way dry-run compiles tractable.
 
+``cfg.lead_pattern`` layers (Kimi K2's leading dense layer) run once each
+before the scan, with parameters and caches of their own under ``"lead"``.
+
 Each block *kind* registers (schema, cache_schema, apply) in KINDS; dense
-attention kinds live here, MoE in models.moe, Mamba2/RWKV6 in models.ssm.
+attention kinds live here, MoE in models.moe, latent attention (MLA) in
+models.mla, Mamba2/RWKV6 in models.ssm.  ``apply`` returns an auxiliary
+value beside the new cache: in training the layer's auxiliary loss; in
+prefill and decode, for the held-expert kind, the pair (its count for the
+serving counters, its router's choice), which ``forward`` sums and
+stacks by layer (``step_counters``, ``step_route``).
 """
 from __future__ import annotations
 
@@ -85,8 +93,11 @@ def _attn_cache_schema(cfg: ModelConfig, B: int, S: int, G: int) -> Dict[str, PS
             "v": PSpec((G, B, S, KV, dh), ax, "zeros")}
 
 
-def insert_kv(cache, k, v, pos):
-    """Write this step's k/v (B,1,KV,dh) into the cache at ``pos``.
+def insert_kv(cache, rows, pos):
+    """Write this step's rows (B, 1, ...) into the cache leaves of the
+    same names (B, S, ...) at ``pos``: ``{"k": k, "v": v}`` for K/V
+    attention, the latent and rotary key for MLA.  Returns the written
+    leaves by name.
 
     ``pos`` is a scalar (whole-batch decode, all rows at the same position)
     or a (B,) vector (slot-based continuous batching: every row of the
@@ -97,15 +108,17 @@ def insert_kv(cache, k, v, pos):
     with jax.named_scope("kv_write"):
         pos = jnp.asarray(pos)
         if pos.ndim == 0:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos,
-                                                          axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, pos,
-                                                          axis=1)
-            return k_cache, v_cache
-        S = cache["k"].shape[1]
-        hit = (jnp.arange(S)[None, :] == pos[:, None])[..., None, None]
-        return (jnp.where(hit, k, cache["k"]),
-                jnp.where(hit, v, cache["v"]))
+            return {name: jax.lax.dynamic_update_slice_in_dim(
+                cache[name], row, pos, axis=1) for name, row in rows.items()}
+        S = cache[next(iter(rows))].shape[1]
+        hit = jnp.arange(S)[None, :] == pos[:, None]
+        hits = {}                       # by the rows' rank: one mask each
+        out = {}
+        for name, row in rows.items():
+            if row.ndim not in hits:
+                hits[row.ndim] = hit[(...,) + (None,) * (row.ndim - 2)]
+            out[name] = jnp.where(hits[row.ndim], row, cache[name])
+        return out
 
 
 def paged_layer_view(pool, group, tables):
@@ -121,14 +134,25 @@ def paged_layer_view(pool, group, tables):
         return jax.tree.map(gather, pool)
 
 
-def written_rows(views, pos):
-    """The row each slot's decode step wrote into its (B, S, ...) view,
-    (B, ...) per leaf: slot i's row ``pos[i]``, clamped to S-1 where the
-    slot wrote nothing (the write-back sends those rows to the null
-    block)."""
+def written_rows(views, pos, block_size):
+    """What each slot's decode step wrote into its (B, S, ...) view, per
+    leaf: slot i's row ``pos[i]``, (B, ...); or, for a leaf whose row is
+    one vector (MLA's latent, views (B, S, width)), the ``block_size``
+    rows of the block holding it, (B, block_size, width).  One vector
+    fills one row of the TPU's (8, 128) memory tiles, and to write it
+    alone the compiler lays the whole pool out anew every step; a block
+    is whole tiles.  Positions past S-1, where the slot wrote nothing,
+    are clamped to it (the write-back sends them to the null block)."""
+    def written(leaf):
+        B = jnp.arange(leaf.shape[0])
+        if leaf.ndim > 3:
+            return leaf[B, pos]
+        rows = (pos // block_size * block_size)[:, None] + jnp.arange(
+            block_size)
+        return leaf[B[:, None], rows]
+
     with jax.named_scope("kv_scatter"):
-        return jax.tree.map(
-            lambda leaf: leaf[jnp.arange(leaf.shape[0]), pos], views)
+        return jax.tree.map(written, views)
 
 
 def _tp_boundary(ctx: ModelCtx, h, mode: str, tag: str):
@@ -159,11 +183,10 @@ def _attention_part(ctx: ModelCtx, p, x, *, window, mode, positions, cache,
     q, k, v = attn_mod.qkv_proj(ctx, p, h, positions, strategy)
     new_cache = {}
     if mode == "decode":
-        k_cache, v_cache = insert_kv(cache, k, v, pos)
+        new_cache = insert_kv(cache, {"k": k, "v": v}, pos)
         out = attn_mod.decode_attention(
-            ctx, q, k_cache, v_cache, pos, window=window,
+            ctx, q, new_cache["k"], new_cache["v"], pos, window=window,
             logit_softcap=cfg.attn.logit_softcap)
-        new_cache = {"k": k_cache, "v": v_cache}
     else:
         out = attn_mod.causal_attention(
             ctx, q, k, v, window=window, logit_softcap=cfg.attn.logit_softcap,
@@ -236,6 +259,9 @@ def lm_schema(cfg: ModelConfig) -> Dict[str, Any]:
         "blocks": blocks,
         "final_norm": PSpec((cfg.d_model,), (None,), "zeros"),
     }
+    if cfg.lead_pattern:
+        schema["lead"] = {f"{i}_{kind}": KINDS[kind]["schema"](cfg, 1)
+                          for i, kind in enumerate(cfg.lead_pattern)}
     if not cfg.tie_embeddings:
         schema["lm_head"] = PSpec((cfg.vocab_size, cfg.d_model),
                                   ("tp_vocab", "fsdp"))
@@ -248,9 +274,15 @@ def lm_schema(cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def cache_schema(cfg: ModelConfig, B: int, S: int) -> Dict[str, Any]:
+    """The decode cache: per block key, leaves (layers, B, S, ...); the
+    lead layers' under ``"lead"``, each with a leading axis of 1."""
     G = cfg.num_groups
-    return {f"{i}_{kind}": KINDS[kind]["cache"](cfg, B, S, G)
-            for i, kind in enumerate(cfg.block_pattern)}
+    out = {f"{i}_{kind}": KINDS[kind]["cache"](cfg, B, S, G)
+           for i, kind in enumerate(cfg.block_pattern)}
+    if cfg.lead_pattern:
+        out["lead"] = {f"{i}_{kind}": KINDS[kind]["cache"](cfg, B, S, 1)
+                       for i, kind in enumerate(cfg.lead_pattern)}
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -263,9 +295,9 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
 
     With ``tables`` (paged decode) ``caches`` is the block pool and the
     scan runs over group indices: each group gathers its own layers' views
-    from the pool, and its scan output is only the row each slot wrote,
-    (G, B, ...) per leaf.  (A group's slice of the pool taken as ``xs``
-    is copied out before the gather reads it.)"""
+    from the pool, and its scan output is only what each slot wrote
+    (``written_rows``), (G, B, ...) per leaf.  (A group's slice of the
+    pool taken as ``xs`` is copied out before the gather reads it.)"""
     cfg, par = ctx.cfg, ctx.par
     shared = params.get("shared_attn")
     blocks = params["blocks"]
@@ -295,52 +327,104 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
         gp, gc = xs
         if tables is not None:          # gc is the group's index
             gc = paged_layer_view(caches, gc, tables)
-        new_gc = {}
+        new_gc, outs = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
             key = f"{i}_{kind}"
             x, nc, a = layer_fns[key](
                 x, gp[key], None if gc is None else gc[key])
             new_gc[key] = nc
+            a, out = _split_aux(a)
+            if out is not None:
+                outs[key] = out
             aux = aux + a
             if mode == "train" and par.sequence_parallel:
                 # saved per-layer inputs stay seq-sharded under remat
                 x = ctx.cons(x, ("batch", "act_seq_sharded", None))
         if tables is not None:
-            new_gc = written_rows(new_gc, pos)
-        return (x, aux), new_gc
+            new_gc = written_rows(new_gc, pos, block_size)
+        return (x, aux), (new_gc, outs)
 
     if mode == "train" and par.remat:
         body = jax.checkpoint(body, prevent_cse=False, policy=policy)
 
+    block_size = (None if tables is None
+                  else jax.tree.leaves(caches)[0].shape[2])
     aux0 = jnp.zeros((), jnp.float32)
     G = cfg.num_groups
     per_group = caches if tables is None else jnp.arange(G)
     if par.scan_layers:
         xs = (blocks, per_group)
-        (x, aux), new_caches = jax.lax.scan(body, (x, aux0), xs)
+        (x, aux), (new_caches, outs) = jax.lax.scan(body, (x, aux0), xs)
     else:
-        ncs = []
+        ncs, outs = [], []
         aux = aux0
         for gi in range(G):
             gp = jax.tree.map(lambda a: a[gi], blocks)
             gc = (None if per_group is None
                   else jax.tree.map(lambda a: a[gi], per_group))
-            (x, aux), nc = body((x, aux), (gp, gc))
+            (x, aux), (nc, out) = body((x, aux), (gp, gc))
             ncs.append(nc)
+            outs.append(out)
         new_caches = (jax.tree.map(lambda *a: jnp.stack(a), *ncs)
                       if ncs and ncs[0] else None)
-    return x, new_caches, aux
+        outs = jax.tree.map(lambda *a: jnp.stack(a), *outs)
+    return x, new_caches, aux, outs
+
+
+def _split_aux(a):
+    """A layer's auxiliary value, and its per-layer outputs or None: the
+    held-expert kind gives (count, router's choice) while serving."""
+    return a if isinstance(a, tuple) else (a, None)
+
+
+def _lead_layers(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
+                 extras, tables=None):
+    """The lead layers, once each and in order, before the scanned groups.
+    Their parameters and caches carry a leading axis of 1; the new caches
+    keep it (with ``tables``, only the written rows, as the scan's)."""
+    cfg, par = ctx.cfg, ctx.par
+    aux = jnp.zeros((), jnp.float32)
+    new, outs = {}, {}
+    for i, kind in enumerate(cfg.lead_pattern):
+        key = f"{i}_{kind}"
+        p = jax.tree.map(lambda a: a[0], params[key])
+        if caches is None:
+            cache = None
+        elif tables is not None:
+            cache = paged_layer_view(caches[key], 0, tables)
+        else:
+            cache = jax.tree.map(lambda a: a[0], caches[key])
+
+        def fn(x, p, cache, kind=kind):
+            return KINDS[kind]["apply"](
+                ctx, p, x, mode=mode, positions=positions, cache=cache,
+                pos=pos, shared=None, extras=extras)
+        if mode == "train" and par.remat:
+            fn = jax.checkpoint(fn, prevent_cse=False)
+        x, nc, a = fn(x, p, cache)
+        if tables is not None:
+            nc = written_rows(nc, pos, jax.tree.leaves(caches)[0].shape[2])
+        new[key] = jax.tree.map(lambda a: a[None], nc)
+        a, out = _split_aux(a)
+        if out is not None:
+            outs[key] = out[None]
+        aux = aux + a
+    return x, new, aux, outs
 
 
 def forward(ctx: ModelCtx, params, tokens, *, mode: str = "train",
             caches=None, pos=None, extras=None, tables=None):
     """tokens (B,St) int32.  mode train|prefill: St=S; decode: St=1.
 
-    Returns (final hidden states (B,St,D), new_caches, aux_loss) — callers
+    Returns (final hidden states (B,St,D), new_caches, aux) — callers
     pick the head: chunked xent for training, last-token logits for serving.
-    Decode with block ``tables`` (B, S // block_size) reads a paged pool
-    as ``caches`` and returns, as ``new_caches``, only the rows written
-    (layers, B, ...) for the caller to scatter into the pool.
+    ``aux`` is the layers' auxiliary values summed (the training loss
+    term); where layers also give per-layer outputs (the held-expert
+    router's choice, in prefill and decode) it is the pair (that sum,
+    {block key: outputs stacked by layer, the lead layers' under
+    "lead"}).  Decode with block ``tables`` (B, S // block_size) reads a
+    paged pool as ``caches`` and returns, as ``new_caches``, only the rows
+    written (layers, B, ...) for the caller to scatter into the pool.
     """
     cfg = ctx.cfg
     x = embed_tokens(ctx, params["embed"], tokens)
@@ -353,9 +437,23 @@ def forward(ctx: ModelCtx, params, tokens, *, mode: str = "train",
         positions = p[:, None] if p.ndim == 1 else jnp.reshape(p, (1,))
     else:
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    x, new_caches, aux = _scan_groups(ctx, params, x, mode=mode,
-                                      positions=positions, caches=caches,
-                                      pos=pos, extras=extras, tables=tables)
+    if cfg.lead_pattern:
+        lead = None if caches is None else caches["lead"]
+        x, lead_new, lead_aux, lead_outs = _lead_layers(
+            ctx, params["lead"], x, mode=mode, positions=positions,
+            caches=lead, pos=pos, extras=extras, tables=tables)
+        if caches is not None:
+            caches = {k: v for k, v in caches.items() if k != "lead"}
+    x, new_caches, aux, outs = _scan_groups(
+        ctx, params, x, mode=mode, positions=positions, caches=caches,
+        pos=pos, extras=extras, tables=tables)
+    if cfg.lead_pattern:
+        new_caches = dict(new_caches or {}, lead=lead_new)
+        aux = aux + lead_aux
+        if lead_outs:
+            outs = dict(outs, lead=lead_outs)
+    if outs:
+        aux = (aux, outs)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     x = ctx.cons(x, ("batch", "act_seq_sharded" if mode == "train"
                      and ctx.par.sequence_parallel else "seq", None))
@@ -433,3 +531,44 @@ from repro.models import vlm as _vlm  # noqa: E402
 
 register_kind("cross", schema=_vlm.cross_schema, cache=_vlm.cross_cache_schema,
               apply=_vlm.apply_cross)
+
+from repro.models import mla as _mla  # noqa: E402
+
+register_kind("mla_dense", schema=_mla.dense_schema,
+              cache=_mla.mla_cache_schema, apply=_mla.apply_dense)
+register_kind("mla_moe", schema=_mla.moe_schema,
+              cache=_mla.mla_cache_schema, apply=_mla.apply_moe)
+
+
+def held_expert_layers(cfg: ModelConfig) -> int:
+    """The number of held-expert (``mla_moe``) layers."""
+    return (cfg.num_groups * cfg.block_pattern.count("mla_moe")
+            + cfg.lead_pattern.count("mla_moe"))
+
+
+def step_counters(cfg: ModelConfig, aux) -> Dict[str, jax.Array]:
+    """The decode step's program counters from ``forward``'s ``aux``:
+    ``experts_hit``, the held experts that some token of the step chose,
+    averaged over the held-expert layers; none for a model without
+    them."""
+    n = held_expert_layers(cfg)
+    return {"experts_hit": _split_aux(aux)[0] / n} if n else {}
+
+
+def step_route(cfg: ModelConfig, aux) -> Optional[jax.Array]:
+    """The held-expert router's choice from ``forward``'s ``aux`` in
+    prefill or decode: (B, T, held-expert layers in order, top_k) int32
+    expert ids over all the router's experts; None for a model without
+    held-expert layers."""
+    outs = _split_aux(aux)[1]
+    if not held_expert_layers(cfg) or outs is None:
+        return None
+    lead = outs.get("lead", {})
+    parts = [lead[f"{i}_{kind}"] for i, kind in enumerate(cfg.lead_pattern)
+             if f"{i}_{kind}" in lead]
+    keys = [f"{i}_{kind}" for i, kind in enumerate(cfg.block_pattern)
+            if f"{i}_{kind}" in outs]
+    if keys:                # (G, keys, B, T, k): layer g * keys + i
+        groups = jnp.stack([outs[k] for k in keys], axis=1)
+        parts.append(groups.reshape((-1,) + groups.shape[2:]))
+    return jnp.moveaxis(jnp.concatenate(parts), 0, 2)

@@ -73,6 +73,33 @@ def _moe_block_flops(cfg, tokens, ctx_len, tokens_per_chip):
     return attn + router + experts
 
 
+def _mla_flops(cfg, tokens, ctx_len, decode: bool):
+    """Per-step global flops of one MLA sub-block: the low-rank
+    projections, then (prefill, train) the expanded heads over the full
+    context, or (decode) the absorbed product against the latent cache."""
+    m, D, H = cfg.mla, cfg.d_model, cfg.num_heads
+    Rq, R = m.q_lora_rank, m.kv_lora_rank
+    dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    proj = 2 * (D * Rq + Rq * H * (dn + dr) + D * (R + dr) + H * dv * D)
+    if decode:
+        absorb = 2 * H * R * (dn + dv)
+        mix = 2 * ctx_len * H * (R + dr) + 2 * ctx_len * H * R
+    else:
+        absorb = 2 * R * H * (dn + dv)          # k_nope and v per token
+        mix = 2 * ctx_len * H * (dn + dr) + 2 * ctx_len * H * dv
+    return tokens * (proj + absorb + mix)
+
+
+def _held_expert_flops(cfg, tokens):
+    """Router, every held expert over every token (the dense masked
+    product), and the shared experts."""
+    m, D = cfg.moe, cfg.d_model
+    F = m.expert_ff or cfg.d_ff
+    _, n = m.held_range
+    return tokens * (2 * D * m.num_experts + n * 6 * D * F
+                     + m.num_shared_experts * 6 * D * F)
+
+
 def _mamba_block_flops(cfg, tokens):
     D = cfg.d_model
     s = cfg.ssm
@@ -116,9 +143,17 @@ def forward_flops(cfg: ModelConfig, shape: ShapeConfig, chips: int) -> float:
         return _whisper_forward(cfg, shape)
     tokens_per_chip = max(tokens // chips * 16, 1)   # per model-row tokens
     total = 0.0
-    G = cfg.num_groups
-    for kind in cfg.block_pattern:
-        if kind in ("attn", "global"):
+    decode = shape.kind == "decode"
+    layers = ([(1, kind) for kind in cfg.lead_pattern]
+              + [(cfg.num_groups, kind) for kind in cfg.block_pattern])
+    for G, kind in layers:
+        if kind == "mla_dense":
+            total += G * (_mla_flops(cfg, tokens, ctx, decode)
+                          + tokens * 6 * cfg.d_model * cfg.d_ff)
+        elif kind == "mla_moe":
+            total += G * (_mla_flops(cfg, tokens, ctx, decode)
+                          + _held_expert_flops(cfg, tokens))
+        elif kind in ("attn", "global"):
             total += G * _attn_block_flops(cfg, tokens, ctx)
         elif kind == "local":
             w = cfg.attn.window or ctx
@@ -178,12 +213,15 @@ def accounting(cfg: ModelConfig, shape: ShapeConfig, chips: int,
     acc = Accounting()
     acc.params = param_count(schema)
     if cfg.moe is not None:
-        # active = total - (non-routed fraction of experts)
-        expert_params = (cfg.num_groups * cfg.moe.num_experts *
-                         3 * cfg.d_model * cfg.d_ff)
-        active_experts = (cfg.num_groups * cfg.moe.top_k *
-                          3 * cfg.d_model * cfg.d_ff)
-        acc.active_params = acc.params - expert_params + active_experts
+        # active = total - (non-routed fraction of the held experts); the
+        # shared experts and dense layers are active for every token
+        m = cfg.moe
+        _, held = m.held_range
+        layers = cfg.num_groups * sum(
+            k in ("moe", "mla_moe") for k in cfg.block_pattern)
+        per_expert = 3 * cfg.d_model * (m.expert_ff or cfg.d_ff)
+        acc.active_params = acc.params - layers * per_expert * (
+            held - min(m.top_k, held))
     else:
         acc.active_params = acc.params
     acc.weight_bytes = acc.params * 2                     # bf16

@@ -443,6 +443,9 @@ def build_rl_train_chunk(cfg: ModelConfig, par: ParallelConfig,
 
 def build_prefill(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
                   shape: ShapeConfig) -> StepBundle:
+    """(params, tokens, *extras) -> (last position's logits, caches,
+    route): ``route`` is a held-expert model's router choice (B, T,
+    layers, top_k) (``transformer.step_route``), else None."""
     cfg = resolve_cfg(cfg, shape)
     mod = _model_module(cfg)
     ctx = ModelCtx(cfg, par, mesh)
@@ -463,11 +466,11 @@ def build_prefill(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
 
     def prefill_step(params, tokens, *extras):
         ex = extras[0] if extras else None
-        hidden, caches, _ = mod.forward(ctx, params, tokens, mode="prefill",
-                                        extras=ex)
+        hidden, caches, aux = mod.forward(ctx, params, tokens,
+                                          mode="prefill", extras=ex)
         # unembed only the last position: (B,1,V), not (B,S,V)
         last = mod.lm_logits(ctx, params, hidden[:, -1:, :])[:, 0, :]
-        return last, caches
+        return last, caches, tfm.step_route(cfg, aux)
 
     last_shd = sh.sharding_for((B, cfg.vocab_size), ("batch", "act_vocab"),
                                mesh, rules)
@@ -475,8 +478,14 @@ def build_prefill(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
         fn=prefill_step,
         abstract_args=(abstract_params, tok_abs) + extra_args,
         in_shardings=(param_shd, tok_shd) + extra_shd,
-        out_shardings=(last_shd, cache_shd),
+        out_shardings=(last_shd, cache_shd, _route_sharding(cfg, mesh)),
     )
+
+
+def _route_sharding(cfg: ModelConfig, mesh: Mesh):
+    """A serving step's router choice is replicated; a model without
+    held-expert layers returns none."""
+    return NamedSharding(mesh, P()) if tfm.held_expert_layers(cfg) else None
 
 
 def build_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
@@ -634,18 +643,21 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int):
 
 
 def paged_cache_scatter(pool, rows, tables, pos):
-    """Write back the one row per slot and layer that the decode step wrote.
+    """Write back what the decode step wrote, per slot and layer.
 
     ``rows`` leaves are (layers, B, ...): slot i wrote position ``pos[i]``
-    (``transformer.written_rows``).  Rows whose position is out of range
-    (free slots parked at 0 with an all-null table, or finished slots past
-    S-1) land on the null block, where duplicate writes are harmless by
-    the masking argument above."""
+    (``transformer.written_rows``); or (layers, B, block_size, ...), the
+    whole block holding that position, for rows too small to write alone.
+    Writes whose position is out of range (free slots parked at 0 with an
+    all-null table, or finished slots past S-1) land on the null block,
+    where duplicate writes are harmless by the masking argument above."""
     def scat(pleaf, rleaf):
         bs = pleaf.shape[2]
         S = tables.shape[1] * bs
         blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
         blk = jnp.where(pos < S, blk, 0)
+        if rleaf.ndim == pleaf.ndim:                  # whole blocks
+            return pleaf.at[:, blk].set(rleaf.astype(pleaf.dtype))
         return pleaf.at[:, blk, pos % bs].set(rleaf.astype(pleaf.dtype))
 
     with jax.named_scope("kv_scatter"):
@@ -671,11 +683,15 @@ def build_paged_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
     """One fused per-slot decode step against the paged pool.  Inside the
     layer scan each layer gathers its own (B, S, ...) views from the pool
     through the block tables (``transformer.paged_layer_view``), runs the
-    slotted layer on them unchanged, and hands out only the row each slot
-    wrote; one scatter after the scan writes those rows into the pool.
+    slotted layer on them unchanged, and hands out only what each slot
+    wrote; one scatter after the scan writes that into the pool.
     No views of the whole pool are built or stacked.  Signature: (params,
-    pool, tables, token, pos) -> (next_token, pool); donate the pool for
-    in-place updates."""
+    pool, tables, token, pos) -> (next_token, pool, counters, route);
+    donate the pool for in-place updates.  ``counters`` holds the step's
+    program counters (``transformer.step_counters``: the experts hit in a
+    held-expert model), and is empty for every other model; ``route`` is
+    a held-expert model's router choice (``transformer.step_route``,
+    (B, 1, layers, top_k)), else None."""
     cfg = resolve_cfg(cfg, shape)
     mod = _model_module(cfg)
     ctx = ModelCtx(cfg, par, mesh)
@@ -706,19 +722,22 @@ def build_paged_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
     repl = NamedSharding(mesh, P())
 
     def paged_step(params, pool, tables, token, pos):
-        hidden, rows, _ = mod.forward(ctx, params, token, mode="decode",
-                                      caches=pool, pos=pos, tables=tables)
+        hidden, rows, aux = mod.forward(ctx, params, token, mode="decode",
+                                        caches=pool, pos=pos, tables=tables)
         logits = mod.lm_logits(ctx, params, hidden)
         next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         pool = paged_cache_scatter(pool, rows, tables, pos)
-        return next_tok[:, None], pool
+        return (next_tok[:, None], pool, tfm.step_counters(cfg, aux),
+                tfm.step_route(cfg, aux))
 
+    counters_shd = {name: repl for name in tfm.step_counters(cfg, 0.0)}
     return StepBundle(
         fn=paged_step,
         abstract_args=(abstract_params, abstract_pool, tab_abs, tok_abs,
                        pos_abs),
         in_shardings=(param_shd, pool_shd, repl, tok_shd, repl),
-        out_shardings=(tok_shd, pool_shd),
+        out_shardings=(tok_shd, pool_shd, counters_shd,
+                       _route_sharding(cfg, mesh)),
         donate_argnums=(1,),
     )
 

@@ -87,6 +87,10 @@ class ServingEngine:
         headroom; shrink it to exercise pressure eviction/preemption.
     prefix_cache:
         Enable radix-style prefix reuse across requests (paged only).
+
+    After each prefill or decode step ``route`` holds a held-expert
+    model's router choice there (``runtime.steps``: (B, T, layers,
+    top_k) expert ids, on the device), and None for other models.
     """
 
     def __init__(self, cfg: ModelConfig, par: ParallelConfig, mesh, *,
@@ -105,6 +109,7 @@ class ServingEngine:
                         else Registry(clock=clock))
         self.clock = clock
         self._slow = SlowSteps(self.metrics, GAUGES.SLOW_STEPS)
+        self.route = None
 
         S = prompt_len + max_new_tokens
         shape = ShapeConfig("serve", S, num_slots, "decode")
@@ -133,9 +138,9 @@ class ServingEngine:
         # admission — admission cost is on the serving critical path
         # (every refill happens between fused decode steps)
         def prefill_insert(params, caches, prompt, slot, *extras):
-            last, small = prefill_fn(params, prompt, *extras)
+            last, small, route = prefill_fn(params, prompt, *extras)
             caches = steps_mod.cache_batch_insert(caches, small, slot)
-            return jnp.argmax(last[0], -1).astype(jnp.int32), caches
+            return jnp.argmax(last[0], -1).astype(jnp.int32), caches, route
 
         self._prefill_insert = jax.jit(prefill_insert, donate_argnums=1)
         ex_abs, _ = steps_mod.extras_specs(cfg, 1)
@@ -179,9 +184,9 @@ class ServingEngine:
             self._slot_meta = [None] * num_slots
 
             def paged_prefill_insert(params, pool, prompt, blocks, *extras):
-                last, small = prefill_fn(params, prompt, *extras)
+                last, small, route = prefill_fn(params, prompt, *extras)
                 pool = steps_mod.paged_prompt_insert(pool, small, blocks)
-                return jnp.argmax(last[0], -1).astype(jnp.int32), pool
+                return jnp.argmax(last[0], -1).astype(jnp.int32), pool, route
 
             self._paged_prefill = jax.jit(paged_prefill_insert,
                                           donate_argnums=1)
@@ -209,12 +214,12 @@ class ServingEngine:
         with self.metrics.span(SPANS.PREFILL) as span:
             if self.paged:
                 blocks = self._tables[slot_index, :self._nb_prompt]
-                first, self._pool = self._paged_prefill(
+                first, self._pool, self.route = self._paged_prefill(
                     self.params, self._pool,
                     jnp.asarray(self._pad_prompt(prompt)),
                     jnp.asarray(blocks), *self._extras)
             else:
-                first, self._caches = self._prefill_insert(
+                first, self._caches, self.route = self._prefill_insert(
                     self.params, self._caches,
                     jnp.asarray(self._pad_prompt(prompt)),
                     jnp.int32(slot_index), *self._extras)
@@ -232,15 +237,20 @@ class ServingEngine:
                 tok = jnp.asarray(np.asarray(tokens, np.int32)[:, None])
                 pos = jnp.asarray(np.asarray(positions, np.int32))
                 tables = jnp.asarray(self._tables) if self.paged else None
+            counters = {}
             with m.span(SPANS.LAUNCH):
                 if self.paged:
-                    out, self._pool = self._paged_decode(
-                        self.params, self._pool, tables, tok, pos)
+                    out, self._pool, counters, self.route = (
+                        self._paged_decode(self.params, self._pool, tables,
+                                           tok, pos))
                 else:
                     out, self._caches = self._decode(self.params,
                                                      self._caches, tok, pos)
+                    self.route = None
             with m.span(SPANS.READBACK):
                 new = np.asarray(out)[:, 0]
+                for name, value in counters.items():
+                    m.gauge(f"serve/{name}", float(value))
         self._slow.end(step, before)
         return new
 

@@ -46,6 +46,9 @@ class BlockPool:
         self.metrics = registry if registry is not None else Registry()
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         self._ref = [0] * num_blocks
+        # blocks at refcount > 0, kept as refcounts change: the gauge is
+        # read on every allocation, and a pool holds tens of thousands
+        self._in_use = 0
         # chain key -> cached block id, in LRU order (oldest first)
         self._lru: "OrderedDict[Tuple, int]" = OrderedDict()
         self._key_of: Dict[int, Tuple] = {}
@@ -59,7 +62,7 @@ class BlockPool:
 
     @property
     def in_use(self) -> int:
-        return sum(1 for b in range(1, self.num_blocks) if self._ref[b] > 0)
+        return self._in_use
 
     def ref(self, block: int) -> int:
         return self._ref[block]
@@ -82,6 +85,7 @@ class BlockPool:
         blocks = [self._free.pop() for _ in range(n)]
         for b in blocks:
             self._ref[b] = 1
+        self._in_use += n
         self._gauge()
         return blocks
 
@@ -105,8 +109,10 @@ class BlockPool:
             if self._ref[b] <= 0:
                 raise ValueError(f"block {b} released below refcount 0")
             self._ref[b] -= 1
-            if self._ref[b] == 0 and b not in self._key_of:
-                self._free.append(b)
+            if self._ref[b] == 0:
+                self._in_use -= 1
+                if b not in self._key_of:
+                    self._free.append(b)
         self._gauge()
 
     # ---------------------------------------------------------- prefix cache
@@ -131,6 +137,7 @@ class BlockPool:
             b = self._lru.get(key)
             if b is None:
                 break
+            self._in_use += self._ref[b] == 0
             self._ref[b] += 1
             self._lru.move_to_end(key)
             blocks.append(b)

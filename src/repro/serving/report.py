@@ -44,6 +44,9 @@ class GAUGES:
     REPLICAS = "serve/replicas"
     SCALE_EVENTS = "serve/scale_events"
     SLOW_STEPS = "serve/slow_steps"
+    # a paged decode step's program counters (runtime.steps
+    # build_paged_decode), one point per step
+    EXPERTS_HIT = "serve/experts_hit"
 
 
 class SPANS:

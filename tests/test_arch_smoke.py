@@ -82,7 +82,7 @@ def test_prefill_then_decode(arch):
     with mesh:
         args = (params, batch["tokens"]) + ((batch["extras"],)
                                             if "extras" in batch else ())
-        last, caches = pb.jit()(*args)
+        last, caches, _ = pb.jit()(*args)
     assert last.shape == (B, cfg.vocab_size)
     assert np.isfinite(np.asarray(last, np.float32)).all(), arch
 

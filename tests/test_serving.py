@@ -214,7 +214,7 @@ def test_slot_decode_matches_scalar_decode(smoke_setup):
     rng = np.random.RandomState(0)
     prompts = rng.randint(1, cfg.vocab_size, (B, Pp)).astype(np.int32)
     with mesh:
-        last, small = prefill(params, jnp.asarray(prompts))
+        last, small, _ = prefill(params, jnp.asarray(prompts))
         tok = jnp.argmax(last, -1).astype(jnp.int32)[:, None]
         pad = jax.jit(steps_mod.cache_prefix_insert)
         cache_a = pad(steps_mod.init_cache(cfg, B, S), small)
@@ -249,7 +249,7 @@ def test_slot_isolation_and_reuse(smoke_setup):
     p1 = rng.randint(1, cfg.vocab_size, (1, Pp)).astype(np.int32)
 
     def solo_reference(prompt):
-        last, small = prefill1(params, jnp.asarray(prompt))
+        last, small, _ = prefill1(params, jnp.asarray(prompt))
         tok = jnp.argmax(last, -1).astype(jnp.int32)[:, None]
         cache = steps_mod.cache_batch_insert(
             steps_mod.init_cache(cfg, B, S), small, 0)
@@ -266,7 +266,7 @@ def test_slot_isolation_and_reuse(smoke_setup):
         ref1 = solo_reference(p1)
 
         # now interleave: r0 in slot 0; after 2 steps admit r1 into slot 1
-        last, small = prefill1(params, jnp.asarray(p0))
+        last, small, _ = prefill1(params, jnp.asarray(p0))
         cache = steps_mod.cache_batch_insert(
             steps_mod.init_cache(cfg, B, S), small, 0)
         t = jnp.concatenate(
@@ -279,7 +279,7 @@ def test_slot_isolation_and_reuse(smoke_setup):
             got0.append(int(t[0, 0]))
             pos[0] += 1
         # admit r1 into slot 1 mid-flight
-        last1, small1 = prefill1(params, jnp.asarray(p1))
+        last1, small1, _ = prefill1(params, jnp.asarray(p1))
         cache = steps_mod.cache_batch_insert(cache, small1, 1)
         t = jnp.stack([t[0], jnp.argmax(last1[0], -1).astype(jnp.int32)[None]])
         pos[1] = Pp

@@ -51,6 +51,29 @@ def test_pool_alloc_release_refcount():
         pool.release([blocks[0]])       # double free
 
 
+def test_pool_in_use_follows_refcounts():
+    """The in-use count, kept as refcounts change, equals the count of
+    blocks held after any sequence of allocations, releases, prefix
+    caching and prefix hits."""
+    rng = np.random.RandomState(0)
+    pool = BlockPool(40, 2)
+    held = []
+    for step in range(400):
+        op = rng.randint(4)
+        if op == 0:
+            got = pool.alloc(rng.randint(1, 4))
+            held += [got] if got else []
+        elif op == 1 and held:
+            pool.release(held.pop(rng.randint(len(held))))
+        elif op == 2 and held:
+            blocks = held[rng.randint(len(held))]
+            pool.cache_prefix([step % 3] * (2 * len(blocks)), blocks)
+        else:
+            got = pool.match([rng.randint(3)] * 6)
+            held += [got] if got else []
+        assert pool.in_use == sum(pool.ref(b) > 0 for b in range(1, 40))
+
+
 def test_pool_exhaustion_allocates_nothing():
     pool = BlockPool(4, 4)
     assert pool.alloc(4) is None        # only 3 usable: all-or-nothing
@@ -525,8 +548,9 @@ def test_paged_step_matches_whole_view_step(serve_setup, arch, positions,
     want_tok, want_pool = _whole_view_step(cfg, s["par"], s["mesh"])(
         params, pool, tables, token, pos)
     want = _bits(want_pool)
-    got_tok, got_pool = _paged_step(cfg, s, B).jit()(   # donates the pool
-        params, pool, tables, token, pos)
+    got_tok, got_pool, counters, route = _paged_step(cfg, s, B).jit()(
+        params, pool, tables, token, pos)           # donates the pool
+    assert counters == {} and route is None
     np.testing.assert_array_equal(np.asarray(got_tok), np.asarray(want_tok))
     for g, w, b in zip(_bits(got_pool), want, before):
         np.testing.assert_array_equal(g, w)

@@ -163,3 +163,51 @@ def test_paged_decode_reads_the_pool_in_place(topo):
                          rf"\[{L},{slots},{S},{KV},{dh}\]", text)
     view = slots * S * KV * dh * 2                            # bf16 bytes
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * view
+
+
+def test_latent_paged_decode_keeps_the_pool_in_place(topo):
+    """Kimi K2's paged decode step at its widths (the dense layer and one
+    MoE layer, 8 slots x 6,144 positions in blocks of 8): the latent pool
+    keeps its row-major layout, so no step copies a pool leaf to gather
+    or to write it (a 576-wide or 64-wide leaf, or one-row writes, make
+    the compiler lay the whole pool out anew every step), and the
+    temporaries stay under two layers' views."""
+    import re
+
+    import numpy as np
+
+    from bench import spec
+    from bench.model_mla_moe import program_config
+    from repro.configs.base import ShapeConfig
+    from repro.models import mla
+    from repro.runtime import steps as steps_mod
+
+    slots, S, bs = 8, 6144, 8
+    cfg = program_config(spec.config("kimi-k2-instruct-ep48")).replace(
+        num_layers=2)
+    nb = 1 + slots * S // bs + 256
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    bundle = steps_mod.build_paged_decode(
+        cfg, registry_parallel(cfg), mesh,
+        ShapeConfig("serve", S, slots, "decode"), block_size=bs,
+        num_blocks=nb)
+    args = [jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s), a, s)
+            for a, s in zip(bundle.abstract_args, bundle.in_shardings)]
+    compiled = jax.jit(
+        bundle.fn, in_shardings=bundle.in_shardings,
+        out_shardings=bundle.out_shardings,
+        donate_argnums=bundle.donate_argnums).lower(*args).compile()
+    text = compiled.as_text()
+    W = mla.latent_width(cfg)
+    assert W == 640
+    pool = rf"bf16\[1,{nb},{bs},\d+\]"
+    assert len(re.findall(rf"{pool}{{3,2,1,0:[^}}]*}} parameter", text)) >= 2
+    # a copy into another layout (a prefetch into on-chip memory keeps it)
+    assert not re.search(rf"= {pool}{{(?!3,2,1,0:)[^}}]*}} copy", text)
+    view = slots * S * W * 2                                  # bf16 bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * view
+
+
+def registry_parallel(cfg):
+    from repro.configs import registry
+    return registry.get_parallel(cfg.name)
