@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import losses
+from repro.models.kvcache import insert_kv
 from repro.models.layers import ModelCtx, rms_norm, swiglu, unembed
 from repro.models.params import PSpec
 from repro.models.transformer import _attn_mlp_schema
@@ -89,7 +90,6 @@ def _self_block(ctx, p, x, *, causal, mode="train", cache=None, pos=None):
     q, k, v = attn_mod.qkv_proj(ctx, p, h, jnp.arange(x.shape[1]), strategy)
     new_cache = {}
     if mode == "decode":
-        from repro.models.transformer import insert_kv
         new_cache = insert_kv(cache, {"k": k, "v": v}, pos)
         out = attn_mod.decode_attention(ctx, q, new_cache["k"],
                                         new_cache["v"], pos)

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, YarnConfig
 from repro.models import attention as attn_mod
+from repro.models import kvcache
 from repro.models.layers import ModelCtx, rms_norm, rope
 from repro.models.params import PSpec
 
@@ -168,26 +169,35 @@ def moe_schema(cfg: ModelConfig, G: int) -> Dict[str, PSpec]:
 
 
 # ---------------------------------------------------------------- attention
-def _absorbed_decode(ctx: ModelCtx, p, q_nope, q_pe, latent, pos, scale):
-    """One token per slot against its cached rows (B, S, latent_width);
-    returns (B, 1, H, dv)."""
+def _absorbed_decode(ctx: ModelCtx, p, q_nope, q_pe, cache, row, pos,
+                     scale):
+    """One token per slot against its cache with the step's own ``row``
+    (B, 1, latent_width) (``kvcache.attend``); returns (B, 1, H, dv) and
+    the layer's new cache."""
     cd = ctx.compute_dtype
     R = p["w_uk"].shape[0]
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, p["w_uk"].astype(cd))
-    pad = latent.shape[-1] - R - q_pe.shape[-1]
+    pad = latent_width(ctx.cfg) - R - q_pe.shape[-1]
     q = jnp.concatenate(
         [q_lat, q_pe, jnp.zeros(q_pe.shape[:-1] + (pad,), cd)], axis=-1)
-    s = jnp.einsum("bthr,bsr->bhts", q, latent).astype(F32) * scale
-    S = latent.shape[1]
-    mask = jnp.arange(S)[None] <= jnp.reshape(pos, (-1, 1))   # (1|B, S)
-    s = jnp.where(mask[:, None, None], s, attn_mod.NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    # the rotary and pad columns ride along rather than cutting the view
-    lat = jnp.einsum("bhts,bsr->bthr", e.astype(cd), latent).astype(F32)
-    lat = lat[..., :R] / jnp.moveaxis(jnp.sum(e, axis=-1), 1, 2)[..., None]
-    return jnp.einsum("bthr,rhv->bthv", lat.astype(cd),
-                      p["w_uv"].astype(cd))
+
+    def dense(views):
+        latent = views["latent"]
+        s = jnp.einsum("bthr,bsr->bhts", q, latent).astype(F32) * scale
+        S = latent.shape[1]
+        mask = jnp.arange(S)[None] <= jnp.reshape(pos, (-1, 1))  # (1|B, S)
+        s = jnp.where(mask[:, None, None], s, attn_mod.NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - m)
+        # the rotary and pad columns ride along rather than cutting the view
+        lat = jnp.einsum("bhts,bsr->bthr", e.astype(cd), latent).astype(F32)
+        den = jnp.moveaxis(jnp.sum(e, axis=-1), 1, 2)[..., None]
+        return (lat[..., :R] / den).astype(cd)
+
+    lat, new_cache = kvcache.attend(cache, {"latent": row}, pos, q, dense,
+                                    scale=scale)
+    return jnp.einsum("bthr,rhv->bthv", lat[..., :R],
+                      p["w_uv"].astype(cd)), new_cache
 
 
 def _expanded(ctx: ModelCtx, p, q_nope, q_pe, c, k_pe, scale):
@@ -206,8 +216,8 @@ def _expanded(ctx: ModelCtx, p, q_nope, q_pe, c, k_pe, scale):
 
 def mla_part(ctx: ModelCtx, p, x, *, mode, positions, cache, pos):
     """Pre-norm MLA sub-block: x + attention, and the new cache (decode:
-    the written views; prefill: the prompt's latents and rotary keys)."""
-    from repro.models.transformer import insert_kv
+    the written views, or on the paged kernel path the step's row;
+    prefill: the prompt's latents and rotary keys)."""
     cfg, m = ctx.cfg, ctx.cfg.mla
     cd = ctx.compute_dtype
     eps = cfg.norm_eps
@@ -224,9 +234,8 @@ def mla_part(ctx: ModelCtx, p, x, *, mode, positions, cache, pos):
         k_pe = _rope(cfg, kv[..., None, R:], positions)[:, :, 0, :]
         new_cache = {}
         if mode == "decode":
-            new_cache = insert_kv(cache, {"latent": _row(cfg, c, k_pe)}, pos)
-            out = _absorbed_decode(ctx, p, q_nope, q_pe,
-                                   new_cache["latent"], pos, scale)
+            out, new_cache = _absorbed_decode(
+                ctx, p, q_nope, q_pe, cache, _row(cfg, c, k_pe), pos, scale)
         else:
             out = _expanded(ctx, p, q_nope, q_pe, c, k_pe, scale)
             if mode == "prefill":

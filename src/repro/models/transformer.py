@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
+from repro.models import kvcache
 from repro.models import losses
 from repro.models.layers import (ModelCtx, cross_entropy, embed_tokens,
                                  rms_norm, swiglu, unembed)
@@ -93,68 +94,6 @@ def _attn_cache_schema(cfg: ModelConfig, B: int, S: int, G: int) -> Dict[str, PS
             "v": PSpec((G, B, S, KV, dh), ax, "zeros")}
 
 
-def insert_kv(cache, rows, pos):
-    """Write this step's rows (B, 1, ...) into the cache leaves of the
-    same names (B, S, ...) at ``pos``: ``{"k": k, "v": v}`` for K/V
-    attention, the latent and rotary key for MLA.  Returns the written
-    leaves by name.
-
-    ``pos`` is a scalar (whole-batch decode, all rows at the same position)
-    or a (B,) vector (slot-based continuous batching: every row of the
-    batch is a different request at its own sequence position).  A vector
-    entry >= cache length writes nothing — a free/overflowed slot is a
-    no-op rather than an out-of-bounds clamp.
-    """
-    with jax.named_scope("kv_write"):
-        pos = jnp.asarray(pos)
-        if pos.ndim == 0:
-            return {name: jax.lax.dynamic_update_slice_in_dim(
-                cache[name], row, pos, axis=1) for name, row in rows.items()}
-        S = cache[next(iter(rows))].shape[1]
-        hit = jnp.arange(S)[None, :] == pos[:, None]
-        hits = {}                       # by the rows' rank: one mask each
-        out = {}
-        for name, row in rows.items():
-            if row.ndim not in hits:
-                hits[row.ndim] = hit[(...,) + (None,) * (row.ndim - 2)]
-            out[name] = jnp.where(hits[row.ndim], row, cache[name])
-        return out
-
-
-def paged_layer_view(pool, group, tables):
-    """Layer group ``group``'s contiguous (B, S, ...) views of a paged
-    pool, whose leaves are (groups, num_blocks, block_size, ...):
-    ``tables`` (B, S // block_size) lists every slot's blocks in order.
-    One gather per leaf, indexed by group and block together, so the
-    group's slice of the pool is never copied out first."""
-    def gather(leaf):
-        g = leaf[group, tables]                   # (B, nb, bs, *tail)
-        return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
-    with jax.named_scope("kv_gather"):
-        return jax.tree.map(gather, pool)
-
-
-def written_rows(views, pos, block_size):
-    """What each slot's decode step wrote into its (B, S, ...) view, per
-    leaf: slot i's row ``pos[i]``, (B, ...); or, for a leaf whose row is
-    one vector (MLA's latent, views (B, S, width)), the ``block_size``
-    rows of the block holding it, (B, block_size, width).  One vector
-    fills one row of the TPU's (8, 128) memory tiles, and to write it
-    alone the compiler lays the whole pool out anew every step; a block
-    is whole tiles.  Positions past S-1, where the slot wrote nothing,
-    are clamped to it (the write-back sends them to the null block)."""
-    def written(leaf):
-        B = jnp.arange(leaf.shape[0])
-        if leaf.ndim > 3:
-            return leaf[B, pos]
-        rows = (pos // block_size * block_size)[:, None] + jnp.arange(
-            block_size)
-        return leaf[B[:, None], rows]
-
-    with jax.named_scope("kv_scatter"):
-        return jax.tree.map(written, views)
-
-
 def _tp_boundary(ctx: ModelCtx, h, mode: str, tag: str):
     """Make the Megatron-SP all-gather an explicit, NAMED value so the
     remat policy (save_only_these_names) can keep it for backward instead
@@ -183,9 +122,12 @@ def _attention_part(ctx: ModelCtx, p, x, *, window, mode, positions, cache,
     q, k, v = attn_mod.qkv_proj(ctx, p, h, positions, strategy)
     new_cache = {}
     if mode == "decode":
-        new_cache = insert_kv(cache, {"k": k, "v": v}, pos)
-        out = attn_mod.decode_attention(
-            ctx, q, new_cache["k"], new_cache["v"], pos, window=window,
+        out, new_cache = kvcache.attend(
+            cache, {"k": k, "v": v}, pos, q,
+            lambda kv: attn_mod.decode_attention(
+                ctx, q, kv["k"], kv["v"], pos, window=window,
+                logit_softcap=cfg.attn.logit_softcap),
+            scale=q.shape[-1] ** -0.5, window=window,
             logit_softcap=cfg.attn.logit_softcap)
     else:
         out = attn_mod.causal_attention(
@@ -294,10 +236,12 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
     """Scan (or unrolled loop) over layer groups; returns (x, new_caches).
 
     With ``tables`` (paged decode) ``caches`` is the block pool and the
-    scan runs over group indices: each group gathers its own layers' views
-    from the pool, and its scan output is only what each slot wrote
-    (``written_rows``), (G, B, ...) per leaf.  (A group's slice of the
-    pool taken as ``xs`` is copied out before the gather reads it.)"""
+    scan runs over group indices: each group's layers read the pool
+    through ``kvcache.paged_layers`` (the paged kernel's handles on a
+    TPU, else the gathered views), and its scan output is only what each
+    slot wrote (``kvcache.written``), (G, B, ...) per leaf.
+    (A group's slice of the pool taken as ``xs`` is copied out before
+    the gather reads it.)"""
     cfg, par = ctx.cfg, ctx.par
     shared = params.get("shared_attn")
     blocks = params["blocks"]
@@ -326,7 +270,7 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
         x, aux = carry
         gp, gc = xs
         if tables is not None:          # gc is the group's index
-            gc = paged_layer_view(caches, gc, tables)
+            gc = kvcache.paged_layers(ctx.mesh, caches, gc, tables)
         new_gc, outs = {}, {}
         for i, kind in enumerate(cfg.block_pattern):
             key = f"{i}_{kind}"
@@ -341,7 +285,7 @@ def _scan_groups(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
                 # saved per-layer inputs stay seq-sharded under remat
                 x = ctx.cons(x, ("batch", "act_seq_sharded", None))
         if tables is not None:
-            new_gc = written_rows(new_gc, pos, block_size)
+            new_gc = kvcache.written(gc, new_gc, pos, block_size)
         return (x, aux), (new_gc, outs)
 
     if mode == "train" and par.remat:
@@ -391,7 +335,8 @@ def _lead_layers(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
         if caches is None:
             cache = None
         elif tables is not None:
-            cache = paged_layer_view(caches[key], 0, tables)
+            cache = kvcache.paged_layers(ctx.mesh, {key: caches[key]}, 0,
+                                         tables)[key]
         else:
             cache = jax.tree.map(lambda a: a[0], caches[key])
 
@@ -403,7 +348,8 @@ def _lead_layers(ctx: ModelCtx, params, x, *, mode, positions, caches, pos,
             fn = jax.checkpoint(fn, prevent_cse=False)
         x, nc, a = fn(x, p, cache)
         if tables is not None:
-            nc = written_rows(nc, pos, jax.tree.leaves(caches)[0].shape[2])
+            nc = kvcache.written({key: cache}, {key: nc}, pos,
+                                 jax.tree.leaves(caches)[0].shape[2])[key]
         new[key] = jax.tree.map(lambda a: a[None], nc)
         a, out = _split_aux(a)
         if out is not None:

@@ -603,7 +603,8 @@ def cache_batch_evict(dst, slot):
 
 
 # ---------------------------------------------------------------------------
-# paged KV pool: block tables + per-layer gather / one-row scatter decode
+# paged KV pool: block tables + in-place reads (or per-layer gathers) /
+# one-row scatter decode
 # ---------------------------------------------------------------------------
 # The slotted cache above dedicates S positions to every slot whether the
 # request uses them or not; the paged layout replaces axis 1 (slots) with a
@@ -613,7 +614,8 @@ def cache_batch_evict(dst, slot):
 # positions point at it, and out-of-range scatter writes are clamped onto
 # it, so its contents are garbage-but-finite — which the decode mask turns
 # into an exact 0.0 contribution (exp(-1e30 - m) == 0.0 in f32), keeping
-# paged decode bit-identical to the slotted baseline.
+# the view path bit-identical to the slotted baseline.  The paged kernel
+# (kernels/paged_decode.py) never reads past a slot's live blocks.
 
 def paged_compatible(cfg: ModelConfig, S: int, block_size: int) -> bool:
     """True iff every cache leaf is a (layers, batch, cache_seq, ...) KV
@@ -646,7 +648,7 @@ def paged_cache_scatter(pool, rows, tables, pos):
     """Write back what the decode step wrote, per slot and layer.
 
     ``rows`` leaves are (layers, B, ...): slot i wrote position ``pos[i]``
-    (``transformer.written_rows``); or (layers, B, block_size, ...), the
+    (``kvcache.written_rows``); or (layers, B, block_size, ...), the
     whole block holding that position, for rows too small to write alone.
     Writes whose position is out of range (free slots parked at 0 with an
     all-null table, or finished slots past S-1) land on the null block,
@@ -680,11 +682,13 @@ def paged_prompt_insert(pool, src, blocks):
 def build_paged_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
                        shape: ShapeConfig, *, block_size: int,
                        num_blocks: int) -> StepBundle:
-    """One fused per-slot decode step against the paged pool.  Inside the
-    layer scan each layer gathers its own (B, S, ...) views from the pool
-    through the block tables (``transformer.paged_layer_view``), runs the
-    slotted layer on them unchanged, and hands out only what each slot
-    wrote; one scatter after the scan writes that into the pool.
+    """One fused per-slot decode step against the paged pool.  On a TPU
+    (``kernels.paged_decode.kernel_path``) each layer's attention is the
+    paged kernel, which reads every slot's live blocks in place through
+    the block tables; elsewhere each layer gathers its own (B, S, ...)
+    views (``kvcache.paged_layer_view``) and runs the slotted layer
+    on them unchanged.  Either way a layer hands out only what each slot
+    wrote, and one scatter after the scan writes that into the pool.
     No views of the whole pool are built or stacked.  Signature: (params,
     pool, tables, token, pos) -> (next_token, pool, counters, route);
     donate the pool for in-place updates.  ``counters`` holds the step's

@@ -237,6 +237,8 @@ class ServingEngine:
                 tok = jnp.asarray(np.asarray(tokens, np.int32)[:, None])
                 pos = jnp.asarray(np.asarray(positions, np.int32))
                 tables = jnp.asarray(self._tables) if self.paged else None
+            if self.paged:
+                m.gauge(GAUGES.KV_LIVE_SHARE, self._live_share(positions))
             counters = {}
             with m.span(SPANS.LAUNCH):
                 if self.paged:
@@ -272,6 +274,14 @@ class ServingEngine:
             self._tables[0] = 0
 
     # ------------------------------------------------------- paged plumbing
+    def _live_share(self, positions) -> float:
+        """The blocks of the slots' views that hold history (positions
+        below each slot's own; ``kernels.paged_decode.history_blocks``
+        with no window), over all the views' blocks: what the paged
+        attention kernel reads of a layer, as a share of the views."""
+        hist = np.minimum(np.asarray(positions), self.cache_len)
+        return float(np.sum(-(-hist // self.block_size))) / self._tables.size
+
     def _admit_paged(self, sched: ContinuousScheduler, slot: Slot) -> bool:
         """Allocate blocks for an admitted request: retain cached shared
         prefix blocks, alloc fresh ones for the rest of the prompt plus

@@ -1,8 +1,9 @@
 """Host-side block-pool bookkeeping for the paged KV cache.
 
 The device side (runtime.steps: ``init_paged_cache``, the decode step's
-per-layer gather ``models.transformer.paged_layer_view`` and its one-row
-write-back ``paged_cache_scatter``) is pure data movement; everything
+reads through the block tables, by ``kernels/paged_decode.py`` on a TPU
+and ``models.kvcache.paged_layer_view`` elsewhere, and its one-row
+write-back ``paged_cache_scatter``) only follows the tables; everything
 *policy* — which block belongs to whom, when it can be reused — lives
 here, mirroring the scheduler/engine split.
 

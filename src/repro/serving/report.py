@@ -47,6 +47,9 @@ class GAUGES:
     # a paged decode step's program counters (runtime.steps
     # build_paged_decode), one point per step
     EXPERTS_HIT = "serve/experts_hit"
+    # the share of a paged decode step's view blocks that hold history,
+    # the blocks the paged attention kernel reads; one point per step
+    KV_LIVE_SHARE = "serve/kv_live_share"
 
 
 class SPANS:

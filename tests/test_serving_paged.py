@@ -699,6 +699,23 @@ def test_engine_spans_each_loop_iteration(serve_setup):
                                                 "train_dispatch"}
 
 
+def test_engine_gauges_the_live_share_of_the_views(serve_setup):
+    """Each paged decode step gauges serve/kv_live_share: the blocks
+    holding history below each slot's position (all S past the end),
+    over the blocks of all the slots' views."""
+    s = serve_setup
+    engine = mk_engine(s, paged=True, block_size=4, prefix_cache=False)
+    S, nb = engine.cache_len, engine.cache_len // 4
+    share = engine.metrics.series(GAUGES.KV_LIVE_SHARE)
+    engine.decode_step([1, 1], [5, 0])
+    engine.decode_step([1, 1], [S + 3, 4])
+    got = [v for _, v in share.snapshot()]
+    assert got == [2 / (2 * nb), (nb + 1) / (2 * nb)]
+    _, m = engine.run(WorkQueue(prompt_requests(s["cfg"], [4, 6, 3])))
+    assert m is engine.metrics          # the two steps above, then the run's
+    assert len(share.snapshot()) == 2 + m.series(GAUGES.DECODE_STEPS).total
+
+
 def test_forced_slow_step_counts_and_carries_notes(serve_setup):
     """A decode step that the (injected) clock makes far slower than the
     running median counts in serve/slow_steps, and its span carries the

@@ -53,6 +53,38 @@ def test_op_scopes_name_the_compiled_ops(setup, build, module, want):
     assert steps_mod.op_scopes(module) is scopes    # kept
 
 
+@pytest.mark.parametrize("arch, scope", [("phi4-mini-3.8b", "attn"),
+                                         ("kimi-k2", "mla")])
+def test_paged_kernel_sits_in_the_attention_scope(setup, monkeypatch, arch,
+                                                  scope):
+    """With the paged attention kernel in the step (as on a TPU; here
+    forced on and interpreted), every op of the kernel maps to the
+    layer's attention scope (``attn``, MLA's ``mla``), the view path's
+    ``kv_gather`` and ``kv_write`` are gone, the write-back stays
+    ``kv_scatter``'s, and the map names every op of the program.  (The
+    Mosaic custom call's own scope: tests/test_tpu_compile.py.)"""
+    from bench.model_mla_moe import program_config
+    from repro.kernels import paged_decode
+    from test_mla_moe import tiny_config
+    monkeypatch.setattr(paged_decode, "kernel_path", lambda *a: True)
+    cfg = (setup["cfg"] if arch != "kimi-k2"
+           else program_config(tiny_config("bfloat16")))
+    bundle = steps_mod.build_paged_decode(
+        cfg, registry.get_parallel(cfg.name), setup["mesh"],
+        ShapeConfig("serve", 16, 2, "decode"), block_size=4, num_blocks=12)
+    bundle.jit()
+    scopes = steps_mod.op_scopes("jit_paged_step")
+    kernel = [path.split("/") for path in scopes.values()
+              if "paged_decode" in path.split("/")]
+    assert kernel and all(
+        scope in parts[:parts.index("paged_decode")] for parts in kernel)
+    names = _scopes_of(scopes)
+    assert "kv_scatter" in names and not {"kv_gather", "kv_write"} & names
+    text = bundle.lower().compile().as_text()
+    assert set(re.findall(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", text,
+                          re.M)) == set(scopes)
+
+
 def test_train_backward_inherits_the_head_scope(setup):
     _train(setup).jit()
     scopes = steps_mod.op_scopes("jit_train_chunk")
