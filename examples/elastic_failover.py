@@ -16,8 +16,8 @@ an unplugged appliance would:
 Asserts, with no manual intervention anywhere: the run reaches its final
 step, every mesh shape kept batch x accum constant, there is a loss value
 for every step, and the loss improved end-to-end.  Emits a
-``CHURN_REPORT {json}`` line consumed by ``benchmarks/run.py`` (recovery
-cost in tokens/s and steps lost is *measured*, not asserted).
+``CHURN_REPORT {json}`` line (recovery cost in tokens/s and steps lost
+is *measured*, not asserted).
 
     PYTHONPATH=src python examples/elastic_failover.py [--fast]
 """

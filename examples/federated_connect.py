@@ -19,12 +19,8 @@ three unequal sites and makes that trade-off measurable:
      survivors, and the migrated step is recorded in its report.
 
     PYTHONPATH=src python examples/federated_connect.py [--fast]
-
-Emits a ``FABRIC_REPORT {json}`` line consumed by
-``benchmarks/run.py::bench_fabric_placement`` / CI.
 """
 import argparse
-import json
 import time
 
 from repro.api import Session, WorkflowRun
@@ -83,7 +79,7 @@ def run_once(cc: ConnectConfig, *, data_blind: bool, kill_site: str = "",
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
-                    help="smaller volumes (CI fabric smoke / benchmark)")
+                    help="smaller volumes (CI fabric smoke)")
     ap.add_argument("--time-scale", type=float, default=0.02,
                     help="real seconds slept per simulated transfer second")
     args = ap.parse_args()
@@ -125,8 +121,6 @@ def main():
     print(out_loc["table"])
     print("\n--- after killing 'sdsc' mid-workflow ---")
     print(out_kill["table"])
-    print("\nFABRIC_REPORT " + json.dumps(
-        {"locality": loc, "blind": bld, "site_kill": kill}))
     saved = bld["bytes_moved"] - loc["bytes_moved"]
     print(f"\nOK — locality placement moved {loc['bytes_moved']:,}B vs "
           f"{bld['bytes_moved']:,}B data-blind (saved {saved:,}B, "

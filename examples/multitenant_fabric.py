@@ -20,12 +20,8 @@ the paper-shaped contracts:
      lag, rendered by the repro.launch.monitor dashboard.
 
     PYTHONPATH=src python examples/multitenant_fabric.py [--fast]
-
-Emits a ``VCLUSTER_REPORT {json}`` line consumed by
-``benchmarks/run.py::bench_vcluster_fairness`` / CI.
 """
 import argparse
-import json
 import threading
 import time
 
@@ -203,7 +199,7 @@ def run_preemption_scenario(fast: bool) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
-                    help="smaller workloads (CI monitor smoke / benchmark)")
+                    help="smaller workloads (CI monitor smoke)")
     args = ap.parse_args()
 
     n_jobs, job_s = (10, 0.05) if args.fast else (12, 0.08)
@@ -233,8 +229,6 @@ def main():
     assert {"sched", "pod", "transfer", "metric", "workload"} <= \
         set(mon["kinds"]), mon
 
-    print("\nVCLUSTER_REPORT " + json.dumps(
-        {"fair": fair, "fifo": fifo, "preemption": prem}))
     print(f"\nOK — fair makespan ratio {fair['makespan_ratio']}x vs FIFO "
           f"skew {fifo['completion_skew']}x; trainer preempted "
           f"{prem['preemptions']}x, lost {prem['steps_lost']} steps, "

@@ -29,12 +29,8 @@ Chaos is injected mid-run and the platform contracts must hold:
      version bump through the federated store.
 
     PYTHONPATH=src python examples/rl_cotenants.py [--fast]
-
-Emits an ``RL_REPORT {json}`` line consumed by
-``benchmarks/run.py::bench_rl`` / CI.
 """
 import argparse
-import json
 import threading
 import time
 
@@ -229,7 +225,7 @@ def run_scenario(fast: bool) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
-                    help="smaller run (CI smoke / benchmark)")
+                    help="smaller run (CI smoke)")
     args = ap.parse_args()
     out = run_scenario(args.fast)
 
@@ -253,7 +249,6 @@ def main():
     assert out["min_actor_syncs"] >= 1, out
     assert out["weight_bytes_pulled"] > 0, out
 
-    print("\nRL_REPORT " + json.dumps(out))
     print(f"\nOK — {out['steps_done']}/{out['steps']} learner steps "
           f"through {out['preemptions']} preemption(s) + "
           f"{out['crashes']} crash(es) (lost {out['steps_lost']} <= "

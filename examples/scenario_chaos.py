@@ -28,9 +28,6 @@ tenants stay within 20% makespan skew, and training completes with the
 elastic bound honored.
 
     PYTHONPATH=src python examples/scenario_chaos.py [--fast]
-
-Emits a ``SCENARIO_REPORT {json}`` line consumed by
-``benchmarks/run.py::bench_scenarios`` / CI.
 """
 import os
 
@@ -39,7 +36,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
                                " --xla_force_host_platform_device_count=8").strip()
 
 import argparse   # noqa: E402
-import json       # noqa: E402
 import time       # noqa: E402
 
 import jax        # noqa: E402
@@ -201,10 +197,9 @@ def finish(result, spec, *, train_steps: int, ckpt_every: int) -> dict:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
-                    help="smaller run (CI scenario smoke / benchmark)")
+                    help="smaller run (CI scenario smoke)")
     args = ap.parse_args()
     rep = run(args.fast)
-    print("\nSCENARIO_REPORT " + json.dumps(rep))
     tenants = rep["tenants"]
     served = sum(t["served"] for t in tenants.values())
     offered = sum(t["offered"] for t in tenants.values())

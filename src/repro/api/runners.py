@@ -40,21 +40,14 @@ def dataclass_kwargs(obj) -> Dict[str, Any]:
 def _resolve_pieces(job, steps: int):
     """Shared (ModelConfig, ParallelConfig, OptimizerConfig) resolution
     for any training-flavoured job (TrainJob / RLJob)."""
+    par = registry.get_parallel(job.arch)
     if job.config is not None:
         cfg = ModelConfig(**job.config)
         base = OptimizerConfig()
-        try:
-            par = registry.get_parallel(job.arch)
-        except KeyError:
-            # a custom model name rode in as the arch (the pre-API
-            # train(cfg.name, cfg_override=cfg) pattern): the config IS
-            # the model, so fall back to default parallelism
-            par = registry.get_parallel("phi4-mini-3.8b")
     else:
         cfg = registry.get_smoke(job.arch) if job.smoke \
             else registry.get_config(job.arch)
         base = registry.get_optimizer(job.arch)
-        par = registry.get_parallel(job.arch)
     okw: Dict[str, Any] = dict(
         lr=1e-3, warmup_steps=max(steps // 20, 1),
         decay_steps=steps, moment_dtype=base.moment_dtype,
@@ -65,9 +58,7 @@ def _resolve_pieces(job, steps: int):
 
 
 def train_pieces(job: TrainJob):
-    """(ModelConfig, ParallelConfig, OptimizerConfig) for a TrainJob —
-    ONE resolution shared by the Session path and the deprecated
-    ``launch.train`` shim, so both train the same model identically."""
+    """(ModelConfig, ParallelConfig, OptimizerConfig) for a TrainJob."""
     return _resolve_pieces(job, job.steps)
 
 

@@ -18,7 +18,7 @@ a migration, which is how a site loss shows up in the Table-I report.
 ``data_blind=True`` is the strawman the paper warns about: round-robin
 over live sites, ignoring where the bytes live.  The federated store's
 pull-through reads keep it *correct*; the meters show what it costs
-(``benchmarks/run.py::bench_fabric_placement``).
+(``examples/federated_connect.py`` asserts the difference).
 """
 from __future__ import annotations
 

@@ -11,10 +11,6 @@ builds the identical manifest.  A single-device run is the degenerate
 case of elastic training (repro.elastic); ``--fail-at`` injects ONE
 crash at that step and the supervisor restores from the latest
 checkpoint within the same invocation.
-
-``train(...)`` is kept as a deprecated shim for existing callers — it
-builds the TrainJob and delegates to ``Session.apply`` (the equivalence
-is pinned by tests/test_api_equivalence.py).
 """
 from __future__ import annotations
 
@@ -26,26 +22,18 @@ from repro.api import Session, TrainJob
 from repro.core.metrics import Registry
 from repro.core.orchestrator import Cluster
 from repro.launch import cli
-from repro.launch.mesh import PRODUCTION_MESH_SHAPE
 
 
 def train_job(arch: str, *, steps: int, seq: int, batch: int, smoke: bool,
               ckpt_dir: str = "", ckpt_every: int = 0, fail_at: int = -1,
-              log_every: int = 10, production_mesh: bool = False,
-              cfg_override=None, seed: int = 0,
+              log_every: int = 10, seed: int = 0,
               device_steps: int = 1) -> TrainJob:
-    """The TrainJob resource the legacy flag surface declares."""
-    config = None
-    if cfg_override is not None:
-        from repro.api.runners import dataclass_kwargs
-        config = dataclass_kwargs(cfg_override)
+    """The TrainJob resource the flag surface declares."""
     return TrainJob(
         name=f"train-{arch}", steps=steps, arch=arch, smoke=smoke,
-        seq_len=seq, global_batch=batch,
-        base_shape=PRODUCTION_MESH_SHAPE if production_mesh else (1, 1),
-        max_data=None if production_mesh else 1,
+        seq_len=seq, global_batch=batch, base_shape=(1, 1), max_data=1,
         ckpt_dir=ckpt_dir, ckpt_every=ckpt_every, keep=2,
-        log_every=log_every, fail_at=fail_at, seed=seed, config=config,
+        log_every=log_every, fail_at=fail_at, seed=seed,
         device_steps=device_steps)
 
 
@@ -57,23 +45,6 @@ def apply_train(spec: TrainJob, *, timeout: float = 3600.0):
     out = session.apply(spec).wait(timeout)
     out["metrics"] = metrics
     return out
-
-
-def train(arch: str, *, steps: int, seq: int, batch: int, smoke: bool,
-          ckpt_dir: str = "", ckpt_every: int = 0, fail_at: int = -1,
-          log_every: int = 10, production_mesh: bool = False,
-          cfg_override=None, device_steps: int = 1):
-    """Deprecated shim — declare a ``repro.api.TrainJob`` and apply it
-    through a ``Session`` instead.  Kept so pre-API callers (and the
-    equivalence regression) keep working unchanged."""
-    spec = train_job(arch, steps=steps, seq=seq, batch=batch, smoke=smoke,
-                     ckpt_dir=ckpt_dir, ckpt_every=ckpt_every,
-                     fail_at=fail_at, log_every=log_every,
-                     production_mesh=production_mesh,
-                     cfg_override=cfg_override, device_steps=device_steps)
-    out = apply_train(spec)
-    return {"losses": out["losses"], "params": out["params"],
-            "metrics": out["metrics"], "report": out["report"]}
 
 
 def main():

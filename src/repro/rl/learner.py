@@ -48,7 +48,7 @@ from repro.runtime import steps as steps_mod
 
 
 class InjectedLearnerFailure(RuntimeError):
-    """The deterministic hard-crash used by tests/benchmarks: raised
+    """The deterministic hard-crash used by tests and examples: raised
     AFTER a step completes, WITHOUT a goodbye save, so the resume path
     pays the real restore-from-periodic-checkpoint cost."""
 

@@ -493,7 +493,7 @@ def build_decode(cfg: ModelConfig, par: ParallelConfig, mesh: Mesh,
     """One fused greedy decode step over the whole batch.
 
     ``per_slot=False``: classic whole-batch decode — every row sits at the
-    same scalar position ``pos`` (the static drain-then-refill server).
+    same scalar position ``pos`` (the per-slot step's test reference).
 
     ``per_slot=True``: continuous-batching decode — ``pos`` is a (B,) int32
     vector, one sequence position per slot.  Cache writes, RoPE and the
@@ -577,10 +577,10 @@ def cache_prefix_insert(dst, src):
     """Copy a short-sequence cache pytree into the front of a longer one.
 
     Prefill emits caches whose sequence axis covers only the prompt;
-    decode needs headroom for the generated tokens.  (The seed's static
-    server skipped this and decoded against the prompt-length cache, so
-    every generated token's K/V write clamped onto the last prompt slot —
-    generations were invisible to attention.)
+    decode needs headroom for the generated tokens.  (Decoding against
+    the prompt-length cache would clamp every generated token's K/V write
+    onto the last prompt slot, leaving generations invisible to
+    attention.)
     """
     def ins(d, s):
         start = (jnp.int32(0),) * d.ndim

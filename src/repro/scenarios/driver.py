@@ -73,8 +73,8 @@ class ScenarioResult:
     burst_states: List[str] = field(default_factory=list)
 
     def report(self) -> Dict[str, Any]:
-        """The JSON-able run summary (what BENCH_scenarios.json rows and
-        the SCENARIO_REPORT stdout line carry)."""
+        """The JSON-able run summary: the chaos events, the fair-share
+        skew and every tenant's report card."""
         return {
             "scenario": self.spec.name,
             "horizon_s": self.spec.horizon_s,

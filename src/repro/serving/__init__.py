@@ -12,8 +12,7 @@ driver; docs/serving.md is the usage guide.
 from repro.serving.engine import ServingEngine
 from repro.serving.pool import BlockPool
 from repro.serving.report import (GAUGES, make_requests, record_serving_totals,
-                                  request_queue, serving_report,
-                                  serving_summary)
+                                  serving_report, serving_summary)
 from repro.serving.router import (Autoscaler, Replica, ReplicaSet,
                                   serve_replicated)
 from repro.serving.scheduler import ContinuousScheduler, Request, Slot
@@ -22,4 +21,4 @@ __all__ = ["ServingEngine", "ContinuousScheduler", "Request", "Slot",
            "BlockPool", "Autoscaler", "Replica", "ReplicaSet",
            "serve_replicated",
            "GAUGES", "make_requests", "record_serving_totals",
-           "request_queue", "serving_report", "serving_summary"]
+           "serving_report", "serving_summary"]

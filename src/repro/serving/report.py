@@ -1,9 +1,8 @@
 """Serving metric names, synthetic request streams and the Table-I row.
 
 One place resolves the ``serve/*`` gauge names: the engine, the
-scheduler, the static-batcher baseline and the report below all import
-``GAUGES`` instead of re-spelling the strings (the old launcher had
-three private copies that had already started to drift).  The report is
+scheduler, the router and the report below all import ``GAUGES``
+instead of re-spelling the strings.  The report is
 total-tolerant: a run that never recorded a stat (e.g. a smoke serve
 with zero completed requests) still renders a row of zeros instead of
 raising.
@@ -80,23 +79,12 @@ def make_requests(n_requests: int, prompt_len: int, gen: int, *,
     return out
 
 
-def request_queue(requests, cfg, *, n_requests, prompt_len, gen, seed,
-                  gen_lens, lease_timeout):
-    """A WorkQueue over explicit ``requests``, or a synthetic stream."""
-    from repro.core.queue import WorkQueue
-    if requests is None:
-        requests = make_requests(n_requests, prompt_len, gen,
-                                 vocab_size=cfg.vocab_size, seed=seed,
-                                 gen_lens=gen_lens)
-    return WorkQueue(requests, lease_timeout=lease_timeout)
-
-
 def record_serving_totals(registry: Registry, useful_tokens: int,
                           wall_s: float, decode_s: float) -> None:
-    """End-of-run serving gauges, shared by every serving driver so the
-    continuous-vs-static benchmark always compares identical accounting:
-    wall time, useful tokens/s overall, and decode-only tokens/s (omitted
-    when the run never decoded, e.g. stop-length-1 workloads)."""
+    """End-of-run serving gauges, shared by the engine and the replica
+    router so both account alike: wall time, useful tokens/s overall,
+    and decode-only tokens/s (omitted when the run never decoded, e.g.
+    stop-length-1 workloads)."""
     registry.gauge(GAUGES.WALL_S, wall_s)
     registry.gauge(GAUGES.TOK_S, useful_tokens / max(wall_s, 1e-9))
     if decode_s > 0:

@@ -1,7 +1,7 @@
 """Slot-based continuous-batching scheduler (host-side policy).
 
 The paper's distributed-inference step feeds many short requests through a
-Redis-style job queue onto GPU pods (§III, §V).  The static batcher served
+Redis-style job queue onto GPU pods (§III, §V).  A static batcher serves
 them drain-then-refill: lease a batch, decode until the *longest* request
 finishes, ack, repeat — every short request idles its decode slot while the
 stragglers run.  This module removes that barrier.

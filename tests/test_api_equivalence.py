@@ -1,15 +1,23 @@
-"""The deprecated launch.train/launch.serve shims and the Session.apply
-path must produce identical results — same losses, same generations,
-same Table-I rows (modulo wall-clock fields) — on the CPU smoke configs.
-Plus the serving-report regression: zero-completed-request runs still
-render a row instead of raising."""
-import jax
+"""A CLI's flag form and its ``--manifest`` form declare the same job and
+give the same results — same losses, same generations, same Table-I rows
+(modulo wall-clock fields) — on the CPU smoke configs.  Both CLIs run
+end to end through ``main()``; an arch the registry does not know is
+refused.  Plus the serving-report regression: zero-completed-request
+runs still render a row instead of raising."""
+import json
+import math
+import re
+import sys
 
-from repro.api import ServeJob, Session, TrainJob
+import pytest
+
+from repro.api import ServeJob, TrainJob, from_manifest
 from repro.core.metrics import Registry, table_one
-from repro.core.orchestrator import Cluster
-from repro.launch.serve import serve, serve_job
-from repro.launch.train import train, train_job
+from repro.launch import cli
+from repro.launch import serve as serve_cli
+from repro.launch import train as train_cli
+from repro.launch.serve import apply_serve, serve_job
+from repro.launch.train import apply_train, train_job
 from repro.serving.report import serving_report
 
 ARCH = "phi4-mini-3.8b"
@@ -19,58 +27,99 @@ ARCH = "phi4-mini-3.8b"
 DETERMINISTIC_ROW_FIELDS = ("requests", "tokens")
 
 
-def test_train_shim_matches_session():
-    shim = train(ARCH, steps=6, seq=16, batch=2, smoke=True, log_every=1)
-    session = Session(cluster=Cluster(devices=jax.devices(),
-                                      metrics=Registry()))
-    spec = train_job(ARCH, steps=6, seq=16, batch=2, smoke=True,
-                     log_every=1)
-    assert isinstance(spec, TrainJob)
-    out = session.apply(spec).wait(300)
-    assert shim["losses"] == out["losses"], "identical optimizer trajectory"
+def test_train_flag_form_matches_manifest_form():
+    flags = train_job(ARCH, steps=6, seq=16, batch=2, smoke=True,
+                      log_every=1)
+    declared = from_manifest(flags.to_manifest())
+    assert isinstance(declared, TrainJob) and declared == flags
+    a, b = apply_train(flags), apply_train(declared)
+    assert a["losses"] == b["losses"], "identical optimizer trajectory"
     for field in ("steps", "global_batch", "seq_len"):
-        assert getattr(shim["report"], field) == \
-            getattr(out["report"], field)
-    assert [s.mesh_shape for s in shim["report"].segments] == \
-        [s.mesh_shape for s in out["report"].segments]
+        assert getattr(a["report"], field) == getattr(b["report"], field)
+    assert [s.mesh_shape for s in a["report"].segments] == \
+        [s.mesh_shape for s in b["report"].segments]
 
 
-def test_serve_shim_matches_session():
-    kw = dict(smoke=True, n_requests=4, prompt_len=8, gen=4, batch=2,
-              gen_lens=[4, 2])
-    shim_results, shim_metrics = serve(ARCH, **kw)
-    session = Session(cluster=Cluster(devices=jax.devices(),
-                                      metrics=Registry()))
-    spec = serve_job(ARCH, **kw)
-    assert isinstance(spec, ServeJob)
-    out = session.apply(spec).wait(300)
-    assert shim_results == out["results"], "identical generations"
-
-    shim_row = serving_report(shim_metrics)
-    api_row = out["report"]
+def test_serve_flag_form_matches_manifest_form():
+    flags = serve_job(ARCH, smoke=True, n_requests=4, prompt_len=8, gen=4,
+                      batch=2, gen_lens=[4, 2])
+    declared = from_manifest(flags.to_manifest())
+    assert isinstance(declared, ServeJob) and declared == flags
+    a, b = apply_serve(flags), apply_serve(declared)
+    assert a["results"] == b["results"], "identical generations"
     for field in DETERMINISTIC_ROW_FIELDS:
-        assert shim_row.extra[field] == api_row.extra[field], field
+        assert a["report"].extra[field] == b["report"].extra[field], field
     # both rows render through the same Table-I machinery
-    assert table_one([shim_row]).splitlines()[0]
-    assert table_one([api_row]).splitlines()[0]
+    assert table_one([a["report"]]).splitlines()[0]
+    assert table_one([b["report"]]).splitlines()[0]
 
 
-def test_train_pieces_accepts_custom_arch_with_config():
-    """The pre-API pattern train(cfg.name, cfg_override=cfg) names a
-    model the registry has never heard of; with a config override the
-    arch must not be forced through the registry."""
+def test_unregistered_arch_is_refused_by_name():
+    """A config does not stand in for the registry: the job's arch names
+    the parallelism too, so an arch the registry does not know is
+    refused with the registry's error, which names it."""
     from repro.api.runners import train_pieces
-    cfg, par, ocfg = train_pieces(TrainJob(
-        name="t", steps=4, arch="lm-20m",
-        config=dict(name="lm-20m", family="dense", num_layers=2,
-                    d_model=32, num_heads=2, num_kv_heads=2, d_ff=64,
-                    vocab_size=128, head_dim=16)))
-    assert cfg.name == "lm-20m" and cfg.d_model == 32
-    assert ocfg.decay_steps == 4
+    job = TrainJob(name="t", steps=4, arch="lm-20m",
+                   config=dict(name="lm-20m", family="dense", num_layers=2,
+                               d_model=32, num_heads=2, num_kv_heads=2,
+                               d_ff=64, vocab_size=128, head_dim=16))
+    with pytest.raises(KeyError, match="lm-20m"):
+        train_pieces(job)
+
+
+SERVE_FLAGS = ["--arch", ARCH, "--smoke", "--requests", "5",
+               "--prompt-len", "8", "--gen", "4", "--slots", "2",
+               "--spread"]
+TRAIN_FLAGS = ["--arch", ARCH, "--smoke", "--steps", "4", "--seq", "16",
+               "--batch", "2"]
+
+
+def _serve_manifest_job():
+    # what --spread declares: stop lengths 4, 2, 1, 1, cycled
+    return serve_job(ARCH, smoke=True, n_requests=5, prompt_len=8, gen=4,
+                     batch=2, gen_lens=[4, 2, 1, 1])
+
+
+def _train_manifest_job():
+    return train_job(ARCH, steps=4, seq=16, batch=2, smoke=True)
+
+
+def _check_serve_output(out):
+    assert "[serve] completed 5 requests" in out
+    totals = {line.split(",")[0]: float(line.split(",")[5])
+              for line in out.splitlines() if line.startswith("serve/")}
+    assert totals["serve/completed"] == 5
+    assert totals["serve/tokens_generated"] == 4 + 2 + 1 + 1 + 4
+    assert "| requests |" in out.replace("  ", " ")
+
+
+def _check_train_output(out):
+    m = re.search(r"\[train\] loss (\S+) -> (\S+)", out)
+    assert m, out
+    assert all(math.isfinite(float(x)) for x in m.groups())
+
+
+@pytest.mark.parametrize("launcher, argv, check", [
+    (serve_cli, SERVE_FLAGS, _check_serve_output),
+    (serve_cli, _serve_manifest_job, _check_serve_output),
+    (train_cli, TRAIN_FLAGS, _check_train_output),
+    (train_cli, _train_manifest_job, _check_train_output),
+], ids=["serve-flags", "serve-manifest", "train-flags", "train-manifest"])
+def test_cli_main_runs_the_smoke_job(launcher, argv, check, tmp_path,
+                                     monkeypatch, capsys):
+    """Each launcher's ``main()`` runs its job end to end, from flags or
+    from a manifest file."""
+    monkeypatch.setattr(cli, "enable_compile_cache", lambda: "")
+    if callable(argv):
+        path = tmp_path / "job.json"
+        path.write_text(json.dumps(argv().to_manifest()))
+        argv = ["--manifest", str(path)]
+    monkeypatch.setattr(sys, "argv", [launcher.__name__] + argv)
+    launcher.main()
+    check(capsys.readouterr().out)
 
 
 def test_serving_summary_only_contains_gauge_names():
-    from repro.core.metrics import Registry
     from repro.serving.report import serving_summary
     keys = set(serving_summary(Registry()))
     assert all(k.startswith("serve/") for k in keys), keys

@@ -158,10 +158,11 @@ def test_build_train_rejects_indivisible_accum():
 # ------------------------------------------------- launcher (thin wrapper)
 
 def test_train_wrapper_degenerate_cluster(tmp_path):
-    from repro.launch.train import train
+    from repro.launch.train import apply_train, train_job
 
-    out = train("phi4-mini-3.8b", steps=6, seq=32, batch=4, smoke=True,
-                ckpt_dir=str(tmp_path / "ck"), ckpt_every=2, log_every=3)
+    out = apply_train(train_job(
+        "phi4-mini-3.8b", steps=6, seq=32, batch=4, smoke=True,
+        ckpt_dir=str(tmp_path / "ck"), ckpt_every=2, log_every=3))
     assert len(out["losses"]) == 6
     assert out["params"] is not None
     rep = out["report"]
@@ -172,14 +173,59 @@ def test_train_wrapper_degenerate_cluster(tmp_path):
 def test_train_wrapper_self_heals_injected_crash(tmp_path):
     """--fail-at crashes once mid-run; the supervisor restores from the
     latest checkpoint and finishes IN THE SAME CALL (seed: raised)."""
-    from repro.launch.train import train
+    from repro.launch.train import apply_train, train_job
 
-    out = train("phi4-mini-3.8b", steps=8, seq=32, batch=4, smoke=True,
-                ckpt_dir=str(tmp_path / "ck"), ckpt_every=2, fail_at=5,
-                log_every=4)
+    out = apply_train(train_job(
+        "phi4-mini-3.8b", steps=8, seq=32, batch=4, smoke=True,
+        ckpt_dir=str(tmp_path / "ck"), ckpt_every=2, fail_at=5,
+        log_every=4))
     assert len(out["losses"]) == 8               # every step accounted for
     outcomes = [s.outcome for s in out["report"].segments]
     assert outcomes[0] == "error" and outcomes[-1] == "done"
+
+
+def test_evicted_segment_resumes_after_its_goodbye_checkpoint(tmp_path):
+    """Checkpoint-then-evict (``Cluster.preempt_pod``, the fair-share
+    scheduler's eviction) with periodic checkpoints off: the segment
+    saves its last step on the way out and the trainer's next segment
+    starts right after it, so no step is lost or re-executed."""
+    import threading
+    import time
+
+    from repro.core.orchestrator import PodState
+    from repro.data.objectstore import ObjectStore
+    from repro.elastic import ElasticTrainer, ElasticTrainSpec
+
+    cluster = Cluster(devices=jax.devices())
+    spec = ElasticTrainSpec(
+        registry.get_smoke("phi4-mini-3.8b"),
+        registry.get_parallel("phi4-mini-3.8b"),
+        OptimizerConfig(warmup_steps=2, decay_steps=100), steps=40,
+        seq_len=32, global_batch=4, base_shape=(1, 1), max_data=1,
+        ckpt_every=0, log_every=0, verbose=False)
+    trainer = ElasticTrainer(cluster, spec,
+                             store=ObjectStore(str(tmp_path / "ck")))
+    evicted = []
+
+    def evict():
+        while trainer.progress < 2:
+            time.sleep(1e-3)
+        pod = next(p for job in cluster.jobs for p in job.pods
+                   if p.state == PodState.RUNNING)
+        evicted.append(cluster.preempt_pod(pod, reason="test eviction"))
+
+    th = threading.Thread(target=evict, daemon=True)
+    th.start()
+    out = trainer.run()
+    th.join(timeout=60)
+    assert evicted == [True]
+    rep = out["report"]
+    first, second = rep.segments
+    assert (first.outcome, second.outcome) == ("preempted", "done")
+    assert 2 <= first.end < spec.steps - 1
+    assert second.start == first.end + 1
+    assert rep.steps_lost == 0
+    assert sorted(out["loss_by_step"]) == list(range(spec.steps))
 
 
 def test_trainer_unschedulable_is_bounded(tmp_path):

@@ -382,6 +382,50 @@ def test_planner_data_blind_round_robin(tmp_path):
         ["s0", "s1", "s0"]
 
 
+def test_locality_moves_fewer_bytes_than_data_blind(tmp_path):
+    """The same two-step workflow on a hub-and-spokes fabric whose data
+    mostly homes at the hub: locality placement runs at the data, the
+    data-blind round robin drags chunks over the slow links, so it moves
+    strictly more bytes and spends more simulated link time for the same
+    answer."""
+    n_chunks = 4
+
+    def run(data_blind):
+        root = tmp_path / ("blind" if data_blind else "local")
+        fabric = Fabric(time_scale=0.0)
+        for name, n in (("hub", 4), ("spoke-a", 2), ("spoke-b", 1)):
+            fabric.add_site(name, devices=list(range(n)),
+                            store_root=str(root / name))
+        fabric.connect("hub", "spoke-a", gbps=0.2, latency_ms=10.0)
+        fabric.connect("hub", "spoke-b", gbps=0.1, latency_ms=20.0)
+        fabric.connect("spoke-a", "spoke-b", gbps=0.1, latency_ms=20.0)
+        fed = FederatedStore(fabric)
+        rng = np.random.RandomState(0)
+        keys = [f"chunks/c{i}.npy" for i in range(n_chunks)]
+        for i, key in enumerate(keys):
+            # all but chunk 0 home at the hub
+            fed.view("hub" if i else "spoke-a").put_array(
+                key, rng.rand(1 << 15))
+        wf = Workflow("placement",
+                      planner=PlacementPlanner(fed, data_blind=data_blind))
+        for i, key in enumerate(keys):
+            wf.add(Step(f"stats{i}", lambda ctx, k=key: {
+                "mean": float(ctx.store.get_array(k).mean())},
+                inputs=[key]))
+        wf.add(Step("reduce", lambda ctx: {"mean": float(np.mean(
+            [v["mean"] for v in ctx.inputs.values()]))},
+            deps=[f"stats{i}" for i in range(n_chunks)]))
+        out = wf.run()
+        m = fabric.metrics
+        return (out["reduce"]["mean"], m.series("fabric/bytes_moved").total,
+                m.series("fabric/transfer_s").total)
+
+    local, blind = run(False), run(True)
+    assert local[0] == blind[0], "placement must not change the answer"
+    assert local[1] < blind[1], (local, blind)
+    assert local[2] < blind[2], (local, blind)
+
+
 def test_planner_glob_expansion(tmp_path):
     fabric = mk_fabric(tmp_path)
     fed = FederatedStore(fabric)

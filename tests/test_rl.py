@@ -279,3 +279,149 @@ def test_rljob_end_to_end_on_cluster():
     assert out["min_actor_syncs"] >= 1
     assert out["final_version"] >= 1
     assert out["steps_lost"] == 0
+
+
+# -------------------------------------------------- learner and actor chaos
+RL_UNIT = RLJob(name="rl-unit", learner_steps=6, actors=1,
+                rollouts_per_step=2, prompt_len=4, max_new_tokens=4,
+                seq_len=12, slots=2, max_policy_lag=2, ckpt_every=2)
+
+
+def mk_learner(tmp_path, *, fail_at=-1):
+    """A learner on the smoke config over a pre-filled rollout queue (all
+    version 0; no mid-run publish, so nothing goes stale)."""
+    from repro.api.runners import rl_pieces
+    from repro.rl import RLLearner, RLLearnerSpec
+    cfg, par, ocfg = rl_pieces(RL_UNIT)
+    spec = RLLearnerSpec(cfg, par, ocfg, steps=RL_UNIT.learner_steps,
+                         seq_len=RL_UNIT.seq_len,
+                         batch=RL_UNIT.rollouts_per_step,
+                         ckpt_every=RL_UNIT.ckpt_every,
+                         broadcast_every=100, fail_at=fail_at)
+    rollouts = RolloutQueue()
+    rng = np.random.RandomState(0)
+    for i in range(24):
+        rollouts.push(Trajectory(
+            ticket=f"t{i}", prompt=tuple(rng.randint(1, 64, 4).tolist()),
+            tokens=tuple(rng.randint(1, 64, 4).tolist()),
+            reward=float(i % 3), policy_version=0, actor="a"))
+    policies = PolicyStore(ObjectStore(str(tmp_path / "policy")))
+    return RLLearner(spec, rollouts, policies,
+                     store=ObjectStore(str(tmp_path / "ckpt")))
+
+
+def test_learner_crash_resumes_from_the_periodic_checkpoint(tmp_path):
+    """A hard crash after step 4 (no goodbye save) restores from the
+    checkpoint at step 3: one step re-executed, within ckpt_every."""
+    learner = mk_learner(tmp_path, fail_at=4)
+    out = learner.run_supervised()
+    rep = learner.report
+    assert out["done"] and rep.steps_done == RL_UNIT.learner_steps
+    assert [s["outcome"] for s in rep.segments] == ["failed", "done"]
+    assert rep.recoveries == 1
+    assert rep.segments[1]["start"] == 4        # not a restart from 0
+    assert rep.steps_lost == 1 <= RL_UNIT.ckpt_every
+    # the re-executed step trained on fresh rollouts, once each
+    assert learner.rollouts.trained == 2 * (RL_UNIT.learner_steps + 1)
+
+
+def test_learner_preemption_resumes_with_zero_lost_steps(tmp_path):
+    """A cooperative stop between periodic checkpoints goodbye-saves the
+    last completed step, so the next placement resumes right after it."""
+    learner = mk_learner(tmp_path)
+    first = learner.run(should_stop=lambda: learner.report.steps_done >= 3)
+    assert first["preempted"] and first["step"] == 2
+    out = learner.run()
+    rep = learner.report
+    assert out["done"] and rep.steps_done == RL_UNIT.learner_steps
+    assert [s["outcome"] for s in rep.segments] == ["preempted", "done"]
+    assert rep.preemptions == 1
+    assert rep.segments[1]["start"] == 3
+    assert rep.steps_lost == 0
+    assert learner.rollouts.trained == 2 * RL_UNIT.learner_steps
+
+
+def test_killed_actor_leases_requeue_to_the_survivor(tmp_path):
+    """An actor killed while it holds ticket leases nacks them at once
+    (not a lease timeout later); its replacement serves them, and every
+    ticket yields exactly one trajectory."""
+    import time
+
+    from repro.api.runners import build_rl_engine, rl_pieces
+    from repro.rl import ActorFleet, RolloutActor, ticket_queue
+
+    cfg, par, _ = rl_pieces(RL_UNIT)
+    tickets = ticket_queue(lease_timeout=300.0)
+    rollouts = RolloutQueue()
+    policies = PolicyStore(ObjectStore(str(tmp_path)))
+    prompts = {}
+    rng = np.random.RandomState(1)
+    n = 12
+    for i in range(n):
+        prompts[f"t{i}"] = rng.randint(1, cfg.vocab_size, 4).tolist()
+        tickets.put({"id": f"t{i}", "prompt": prompts[f"t{i}"],
+                     "max_new_tokens": RL_UNIT.max_new_tokens})
+    fleet = ActorFleet(
+        lambda name: RolloutActor(name, build_rl_engine(RL_UNIT, cfg, par),
+                                  tickets, rollouts, policies,
+                                  prompts=prompts),
+        width=1)
+    fleet.start()
+    try:
+        deadline = time.monotonic() + 120
+        while tickets.leased_by("actor-0") == 0:
+            assert time.monotonic() < deadline, "actor-0 never leased"
+            time.sleep(1e-3)
+        fleet.kill("actor-0")
+        assert tickets.leased == 0, "the kill must nack, not strand, leases"
+        assert fleet.resize(1) == 1             # spawns actor-1
+        while rollouts.pushed < n:
+            assert time.monotonic() < deadline, rollouts.pushed
+            time.sleep(5e-3)
+    finally:
+        fleet.stop_all()
+    got = rollouts.take_fresh(2 * n, worker="audit", current_version=0,
+                              max_policy_lag=0)
+    assert sorted(t.ticket for _, t in got) == sorted(prompts)
+    attempts = [a for _, _, a, _, _ in tickets.snapshot()["tasks"]]
+    assert max(attempts) > 1, "no ticket was requeued"
+    assert not tickets.dead
+
+
+def test_actor_pulls_published_weights_across_the_fabric():
+    """The learner publishes at its site; an actor polling through its
+    own site's view pulls on a version bump only, and each pull is a
+    metered cross-link transfer."""
+    import jax
+
+    from repro.api.runners import build_rl_engine, rl_pieces
+    from repro.fabric import Fabric, FederatedStore
+    from repro.rl import RolloutActor, ticket_queue
+
+    fabric = Fabric()
+    fabric.add_site("serve", devices=[0])
+    fabric.add_site("train", devices=[0])
+    fabric.connect("serve", "train", gbps=10.0, latency_ms=1.0)
+    fed = FederatedStore(fabric)
+    reg = Registry()
+    cfg, par, _ = rl_pieces(RL_UNIT)
+    engine = build_rl_engine(RL_UNIT, cfg, par)
+    actor = RolloutActor("a", engine, ticket_queue(), RolloutQueue(),
+                         PolicyStore(fed.view("serve"), registry=reg),
+                         prompts={})
+    publish = PolicyStore(fed.view("train"))
+    moved = fabric.metrics.series("fabric/bytes_moved")
+
+    assert not actor.maybe_sync()               # nothing published yet
+    new = jax.tree.map(lambda x: x + 1, engine.params)
+    publish.publish(1, new, step=2)
+    assert actor.maybe_sync()
+    assert actor.version == 1 and actor.syncs == 1
+    pulled = moved.total
+    assert pulled > 0
+    leaf = jax.tree.leaves(new)[0]
+    np.testing.assert_array_equal(np.asarray(jax.tree.leaves(engine.params)[0]),
+                                  np.asarray(leaf))
+    assert not actor.maybe_sync()               # no bump, no pull
+    assert moved.total == pulled
+    assert reg.series("rl/weight_syncs").total == 1

@@ -316,11 +316,13 @@ def test_continuous_serve_end_to_end():
     """Heterogeneous stop lengths through the real engine on the smoke
     config: every request completes at exactly its stop length and the
     metrics totals agree with the results."""
-    from repro.launch.serve import serve
+    from repro.launch.serve import apply_serve, serve_job
 
     gens = [6, 2, 4, 1, 6]
-    results, metrics = serve("phi4-mini-3.8b", smoke=True, n_requests=5,
-                             prompt_len=8, gen=6, batch=2, gen_lens=gens)
+    out = apply_serve(serve_job("phi4-mini-3.8b", smoke=True, n_requests=5,
+                                prompt_len=8, gen=6, batch=2,
+                                gen_lens=gens))
+    results, metrics = out["results"], out["metrics"]
     assert sorted(results) == [0, 1, 2, 3, 4]
     assert [len(results[i]) for i in range(5)] == gens
     s = metrics.summary()
@@ -376,10 +378,12 @@ def test_continuous_serve_audio_family():
     cache, so the engine must budget prompt + generation inside
     decoder_len — a regression here silently no-ops every generated
     token's K/V write."""
-    from repro.launch.serve import serve
+    from repro.launch.serve import apply_serve, serve_job
 
     gens = [4, 2, 1]
-    results, metrics = serve("whisper-small", smoke=True, n_requests=3,
-                             prompt_len=8, gen=4, batch=2, gen_lens=gens)
+    out = apply_serve(serve_job("whisper-small", smoke=True, n_requests=3,
+                                prompt_len=8, gen=4, batch=2,
+                                gen_lens=gens))
+    results, metrics = out["results"], out["metrics"]
     assert [len(results[i]) for i in range(3)] == gens
     assert metrics.summary()["serve/tokens_generated"]["total"] == sum(gens)

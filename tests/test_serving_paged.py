@@ -604,6 +604,29 @@ def test_paged_prefix_reuse_hits_and_refcounts(serve_setup):
     assert engine.block_pool.cached_blocks >= 1
 
 
+def test_paged_prefix_fleet_serves_everything_with_prefix_hits(serve_setup):
+    """Real paged engines behind the session-affine router: a stream
+    whose prompts share one block-aligned head is served whole, each
+    request at its own stop length, and the replicas' prefix caches hit
+    on the shared head."""
+    s = serve_setup
+    gens = [4, 1, 2, 1] * 4
+    reqs = prompt_requests(s["cfg"], gens, seed=5, shared_prefix=4)
+    for r in reqs:
+        r["session"] = f"user-{r['id'] % 4}"
+    results, metrics, _ = serve_replicated(
+        lambda name, reg: mk_engine(s, paged=True, block_size=4,
+                                    prefix_cache=True, registry=reg,
+                                    seed=0),
+        reqs, min_replicas=2, max_replicas=2, registry=Registry(),
+        reconcile_interval=0.005, timeout_s=300.0)
+    assert sorted(results) == list(range(len(gens)))
+    assert [len(results[i]) for i in range(len(gens))] == gens
+    assert metrics.series(GAUGES.COMPLETED).total == len(gens)
+    assert metrics.series(GAUGES.PREFIX_HITS).total > 0
+    assert metrics.series(GAUGES.TOK_S).last > 0
+
+
 def test_paged_pool_pressure_preempts_youngest_and_recovers(serve_setup):
     """A pool too small for two full generations: the youngest slot is
     nacked back to the queue when the elder needs its next block, and
